@@ -30,9 +30,13 @@ from typing import Optional
 from repro.core.rollup import quantile_of
 
 # --------------------------------------------------------------------------
-# Hardware constants (assignment: TPU v5e-class chip)
+# Hardware constants: one TPU v5e chip
 # --------------------------------------------------------------------------
 
+# ``jax.Device.device_kind`` of the chip these peaks describe.  Source:
+# Google Cloud documentation, "TPU v5e" page — 197 TFLOP/s bf16, 819 GB/s
+# HBM, 1,600 Gbit/s of interchip interconnect over 4 links (50 GB/s each).
+PEAKS_DEVICE_KIND = "TPU v5 lite"
 PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
 HBM_BW = 819e9               # bytes/s per chip
 ICI_BW = 50e9                # bytes/s per link (~ per chip per direction)
@@ -42,6 +46,17 @@ HW_CONSTANTS = {
     "HBM_BW": HBM_BW,
     "ICI_BW": ICI_BW,
 }
+
+
+def check_device_peaks(device) -> None:
+    """Refuse a TPU whose peaks are not the ones above: every rate formula
+    divides by them, so a job on another chip would report shares of the
+    wrong peak.  Non-TPU devices (the CPU test path) report no device
+    shares and pass."""
+    if device.platform == "tpu" and device.device_kind != PEAKS_DEVICE_KIND:
+        raise ValueError(
+            f"no peaks for TPU kind {device.device_kind!r}; "
+            f"core/perf_groups.py holds {PEAKS_DEVICE_KIND!r} only")
 
 
 # --------------------------------------------------------------------------

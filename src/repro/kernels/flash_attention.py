@@ -25,7 +25,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from repro.compat import CompilerParams
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -111,6 +110,8 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 
     kernel = functools.partial(_attn_kernel, scale=scale, causal=causal,
                                window=window, bq=bq, bk=bk, seq_len=s)
+    ce = cost_estimate(q.shape, kv, q.dtype.itemsize, causal=causal,
+                       window=window, bk=bk)
     return pl.pallas_call(
         kernel,
         grid=grid,
@@ -130,20 +131,24 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
         name="flash_attention",
+        cost_estimate=pl.CostEstimate(flops=int(ce["flops"]),
+                                      transcendentals=0,
+                                      bytes_accessed=int(ce["bytes"])),
     )(q, k, v)
 
 
 def cost_estimate(q_shape, kv_heads: int, itemsize: int, *,
                   causal: bool = True, window: int = 0,
                   bk: int = 128) -> dict:
-    """Analytic per-call ``{flops, bytes}`` for one flash_attention call
-    (the marker-region roofline fallback when HLO cost analysis is
-    unavailable — e.g. interpret-mode lowering).
+    """Analytic per-call ``{flops, bytes}`` for one flash_attention call:
+    declared to the compiler as the kernel's ``pl.CostEstimate`` (what the
+    HLO walk reads back from the compiled kernel) and used as is in
+    interpret mode.
 
     FLOPs: the two MXU contractions, 2*S_q*S_kv*D each for QK^T and PV;
     causal masking skips roughly half the key blocks, a sliding window

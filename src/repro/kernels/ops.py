@@ -9,10 +9,11 @@ Marker instrumentation (``repro.core.marker``): :func:`set_kernel_markers`
 installs a ``MarkerSession`` and every *eager* wrapper call becomes a
 ``kernel:<name>`` region — synced with ``block_until_ready`` inside the
 region so the wall time is the kernel's, and seeded with static per-call
-flops/bytes so the region carries its own roofline operands.  Costs come
-from ``launch/hlo_analysis`` over the lowered artifact when that is
-meaningful (compiled Mosaic), else from the kernels' analytic
-``cost_estimate`` helpers; either way they are memoized per shape.  Calls
+flops/bytes so the region carries its own roofline operands.  A compiled
+kernel's costs come from ``launch/hlo_analysis`` over its artifact (the
+flops the kernel declares as its ``pl.CostEstimate``, the bytes its
+operands and result move); interpret mode takes the kernels' analytic
+``cost_estimate`` helpers.  Either way they are memoized per shape.  Calls
 made under a jax trace (inside ``jit``) are never instrumented — a traced
 wrapper body runs once at trace time, so timing it would be noise — and
 uninstrumented calls pay nothing (one ``None`` check, no sync).
@@ -46,25 +47,20 @@ def _eager(*arrays) -> bool:
 
 def _costs(key, lower_fn, analytic_fn, interpret: bool) -> dict:
     """Memoized per-call static costs.  Interpret-mode lowering emulates
-    the kernel with callbacks (its HLO costs are meaningless), so it goes
-    straight to the analytic estimate; compiled lowerings prefer the HLO
-    walk and fall back to analytic when it fails or reports nothing."""
+    the kernel with callbacks (its HLO costs are meaningless), so it takes
+    the analytic estimate; a compiled kernel takes the HLO walk over its
+    artifact, and a walk that fails or finds no work raises."""
     c = _COSTS.get(key)
     if c is not None:
         return c
-    c = None
-    if not interpret:
-        try:
-            from repro.launch.hlo_analysis import analyze_hlo
-            per = analyze_hlo(lower_fn().compile().as_text())["per_device"]
-            c = {"flops": float(per["flops"]),
-                 "bytes": float(per["bytes"])}
-            if c["flops"] <= 0.0 or c["bytes"] <= 0.0:
-                c = None
-        except Exception:
-            c = None
-    if c is None:
+    if interpret:
         c = analytic_fn()
+    else:
+        from repro.launch.hlo_analysis import analyze_hlo
+        per = analyze_hlo(lower_fn().compile().as_text())["per_device"]
+        c = {"flops": float(per["flops"]), "bytes": float(per["bytes"])}
+        if c["flops"] <= 0.0 or c["bytes"] <= 0.0:
+            raise ValueError(f"HLO walk found no work in {key[0]}: {c}")
     _COSTS[key] = c
     return c
 

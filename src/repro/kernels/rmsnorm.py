@@ -13,9 +13,10 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.compat import CompilerParams
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+_VMEM_BLOCK_BUDGET = 8 << 20
 
 
 def _rmsnorm_kernel(x_ref, scale_ref, o_ref, *, eps: float):
@@ -34,9 +35,12 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, bn: int = 256,
     d = x.shape[-1]
     xf = x.reshape(-1, d)
     n = xf.shape[0]
-    bn = min(bn, n)
+    # the in and out blocks, double-buffered, must fit the 16 MiB of scoped
+    # VMEM a TPU kernel gets: fp32 rows of d=4096 allow 128 of them
+    bn = min(bn, n, max(8, _VMEM_BLOCK_BUDGET // (4 * d * x.dtype.itemsize)))
     while n % bn != 0:                 # ragged fallback for odd row counts
         bn -= 1
+    ce = cost_estimate(x.shape, x.dtype.itemsize)
     out = pl.pallas_call(
         functools.partial(_rmsnorm_kernel, eps=eps),
         grid=(n // bn,),
@@ -46,17 +50,22 @@ def rmsnorm(x, scale, *, eps: float = 1e-5, bn: int = 256,
         ],
         out_specs=pl.BlockSpec((bn, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
         name="rmsnorm",
+        cost_estimate=pl.CostEstimate(flops=int(ce["flops"]),
+                                      transcendentals=n,
+                                      bytes_accessed=int(ce["bytes"])),
     )(xf, scale)
     return out.reshape(orig_shape)
 
 
 def cost_estimate(x_shape, itemsize: int) -> dict:
-    """Analytic per-call ``{flops, bytes}`` for one rmsnorm call (the
-    marker-region roofline fallback).  Bandwidth-bound by design: ~4
+    """Analytic per-call ``{flops, bytes}`` for one rmsnorm call: declared
+    to the compiler as the kernel's ``pl.CostEstimate`` (what the HLO walk
+    reads back from the compiled kernel) and used as is in interpret mode.
+    Bandwidth-bound by design: ~4
     VPU ops per element (square, mean-accumulate, rsqrt-scale, gain)
     against one read + one write of x plus the scale vector.
     """

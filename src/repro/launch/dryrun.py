@@ -33,7 +33,7 @@ import jax
 from repro.configs import (ASSIGNED_ARCHS, SHAPES, TrainConfig, get_config,
                            supports_shape)
 from repro.core.analysis import RooflineAnalyzer
-from repro.launch.hlo_analysis import analyze_hlo, cost_analysis_dict
+from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_bundle, lower_bundle
 
@@ -113,7 +113,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_dir: str,
             "temp_bytes": getattr(mem, "temp_size_in_bytes", 0),
             "alias_bytes": getattr(mem, "alias_size_in_bytes", 0),
         }
-        ca = cost_analysis_dict(compiled)
+        ca = compiled.cost_analysis()
         record["xla_cost_analysis"] = {
             "flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0)),

@@ -48,6 +48,10 @@ _GROUPS_V2_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_V1_RE = re.compile(r"replica_groups=\{\{([0-9, ]*)\}")
 _LHS_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _OPERAND_RE = re.compile(r"%([\w\.\-]+)")
+# a Pallas kernel's declared ``pl.CostEstimate``, as the TPU backend embeds
+# it in the ``tpu_custom_call`` backend config
+_KERNEL_COST_RE = re.compile(
+    r'"cost_estimate":\{"flops":"(\d+)","transcendentals":"(\d+)"')
 
 _TRANSCENDENTAL = {"exponential", "log", "tanh", "power", "rsqrt", "sqrt",
                    "logistic", "sine", "cosine", "erf", "exponential-minus-one",
@@ -251,10 +255,13 @@ class HloAnalyzer:
 
     @staticmethod
     def _operand_text(line: str) -> str:
-        """Text inside the opcode's operand parens (balance-aware)."""
-        start = line.find("(", line.find(" = "))
-        if start < 0:
+        """Text inside the opcode's operand parens (balance-aware).  The
+        search starts at the opcode: a TPU layout in the result type
+        (``{1,0:T(8,128)(2,1)}``) has parens of its own."""
+        m = _INSTR_RE.match(line)
+        if m is None:
             return ""
+        start = m.end() - 1
         depth = 0
         for i in range(start, len(line)):
             if line[i] == "(":
@@ -497,7 +504,15 @@ class HloAnalyzer:
                           "scatter", "dynamic-slice", "dynamic-update-slice",
                           "concatenate", "pad", "rng-bit-generator",
                           "convolution")
-        if op == "dot":
+        if op == "custom-call":
+            # a Mosaic kernel is opaque to the walk: its flops are the ones
+            # the kernel declares; its operands and results cross HBM
+            m = _KERNEL_COST_RE.search(instr.line)
+            if m:
+                cost.flops += float(m.group(1))
+                cost.transcendentals += float(m.group(2))
+                hbm_real = True
+        elif op == "dot":
             cost.flops += _dot_flops(instr, self._shapes)
         elif op in ("reduce", "reduce-window"):
             cost.flops += self._operand_elems_first(instr)
@@ -540,22 +555,6 @@ class HloAnalyzer:
         for d in dims:
             n *= d
         return n
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` normalized across JAX versions.
-
-    Older JAX returns a one-element list of dicts (one per program),
-    newer JAX returns the dict directly; either way callers want a plain
-    dict (empty when XLA reports nothing).
-    """
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca) if ca else {}
 
 
 def analyze_hlo(hlo_text: str) -> dict:

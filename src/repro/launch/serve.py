@@ -29,9 +29,11 @@ def main(argv=None) -> int:
 
     from repro.configs import get_config
     from repro.core import MonitoringStack
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.transformer import init_model_params
     from repro.serve.engine import ServingEngine
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     params = init_model_params(cfg, seed=0)
     if args.ckpt_dir:

@@ -63,11 +63,13 @@ def main(argv=None) -> int:
 
     from repro.configs import ShapeConfig, TrainConfig, get_config
     from repro.core import MonitoringStack
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_mesh_for
-    from repro.launch.steps import make_pc
+    from repro.launch.steps import build_train_bundle, make_pc
     from repro.parallel.sharding import rules_for
     from repro.train.loop import train
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     shape = ShapeConfig("cli", seq_len=args.seq_len,
                         global_batch=args.global_batch, kind="train")
@@ -80,13 +82,15 @@ def main(argv=None) -> int:
         monitor=not args.no_monitor)
 
     ndev = len(jax.devices())
-    mesh = pc = None
+    mesh = pc = in_shardings = None
     if ndev > 1:
         mesh = make_mesh_for(ndev, model=args.tp)
         rules = rules_for("train")
         if args.grad_compression != "none" and "pod" in mesh.axis_names:
             rules = rules.with_overrides(batch=("data",))
         pc = make_pc(rules, mesh)
+        in_shardings = build_train_bundle(cfg, shape, tcfg, mesh,
+                                          rules).in_shardings
         print(f"mesh: {dict(zip(mesh.axis_names, mesh.devices.shape))}")
 
     stack = MonitoringStack.inprocess(out_dir=args.lms_out,
@@ -103,6 +107,7 @@ def main(argv=None) -> int:
                   f"grad {float(metrics['grad_norm']):.3f}", flush=True)
 
     result = train(cfg, tcfg, shape, stack=stack, pc=pc, mesh=mesh,
+                   in_shardings=in_shardings,
                    fail_at_step=args.fail_at_step, step_callback=cb,
                    user=args.user)
     print(f"done: steps={result.steps_run} final_loss={result.last_loss:.4f}"

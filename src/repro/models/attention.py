@@ -315,10 +315,10 @@ def gqa_attention(p, x, cfg: ModelConfig, *, rope=None, mode="train",
     if mode == "train":
         if attn_impl == "flash" and not cfg.attn_logit_softcap:
             # Pallas blocked online-softmax kernel (TPU Mosaic; interpret
-            # mode on CPU).  S^2 scores never leave VMEM — see
-            # kernels/flash_attention.py and EXPERIMENTS.md §Perf.
+            # mode on the CPU backend only).  S^2 scores never leave VMEM —
+            # see kernels/flash_attention.py.
             from repro.kernels.ops import flash_attention_bshd
-            interpret = jax.default_backend() != "tpu"
+            interpret = jax.default_backend() == "cpu"
             out = flash_attention_bshd(q, k, v, causal=causal,
                                        window=window, interpret=interpret)
         elif attn_impl == "recursive" and causal and s >= 512:

@@ -33,7 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import apply_mlp, mlp_specs

@@ -15,6 +15,7 @@ tuning.
 from __future__ import annotations
 
 import math
+import zlib
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -89,7 +90,9 @@ def init_params(specs, seed: int = 0):
     out = []
     for (path, s) in paths:
         pstr = "/".join(str(p) for p in path)
-        key = jax.random.fold_in(base, hash(pstr) % (2 ** 31))
+        # crc32, not hash(): str hashing is salted per process, and the
+        # same seed must give the same weights in every run
+        key = jax.random.fold_in(base, zlib.crc32(pstr.encode()) % (2 ** 31))
         out.append(_init_leaf(s, key))
     return jax.tree.unflatten(treedef, out)
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
                    axis: str = "pipe", num_microbatches: int = 4):

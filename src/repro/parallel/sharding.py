@@ -168,6 +168,14 @@ class PartitionConstraints:
         if not self.enable:
             return x
         pspec = logical_to_pspec(logical_axes, x.shape, self.rules, self.mesh)
+        return self._apply(x, pspec)
+
+    def _apply(self, x, pspec):
+        # inside a partial-manual shard_map (the per-pod gradient region)
+        # GSPMD propagates the auto axes itself: a constraint naming two
+        # auto axes there aborts XLA's SPMD partitioner (JAX 0.9)
+        if jax.sharding.get_abstract_mesh().manual_axes:
+            return x
         return jax.lax.with_sharding_constraint(
             x, NamedSharding(self.mesh, pspec))
 
@@ -197,8 +205,7 @@ class PartitionConstraints:
         rules = self.rules.with_overrides(seq="model", embed=None)
         pspec = logical_to_pspec(("batch", "seq", "embed"), x.shape, rules,
                                  self.mesh)
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, pspec))
+        return self._apply(x, pspec)
 
     def heads(self, x):                        # (B, S, H, D)
         return self.act(x, "batch", "seq", "heads", None)
@@ -221,8 +228,7 @@ class PartitionConstraints:
             axes = ("batch", "cache_seq", None, None)
         rules = self.rules.with_overrides(cache_seq="model")
         pspec = logical_to_pspec(axes, x.shape, rules, self.mesh)
-        return jax.lax.with_sharding_constraint(
-            x, NamedSharding(self.mesh, pspec))
+        return self._apply(x, pspec)
 
     def expert_buffer(self, x):                # (E, C, d)
         return self.act(x, "experts", None, None)

@@ -29,19 +29,29 @@ def make_serve_fns(cfg: ModelConfig, *, pc=None, donate_cache: bool = True):
 
     prefill(params, tokens, cache, extras) -> (last_logits, cache)
     decode(params, cache, tokens, pos, extras) -> (logits, cache)
+
+    The logits keep the padded vocab width; the padding columns (ids the
+    tokenizer does not have) are masked to -inf so no sampler picks them.
     """
+
+    def last(logits):
+        lg = logits[:, -1]
+        if cfg.vocab_padded == cfg.vocab_size:
+            return lg
+        pad = jnp.arange(cfg.vocab_padded) >= cfg.vocab_size
+        return jnp.where(pad, -jnp.inf, lg)
 
     def prefill(params, tokens, cache, extras=None):
         logits, cache, _ = forward(params, cfg, tokens=tokens,
                                    mode="prefill", cache=cache, pc=pc,
                                    extras=extras or {})
-        return logits[:, -1], cache
+        return last(logits), cache
 
     def decode(params, cache, tokens, pos, extras=None):
         logits, cache, _ = forward(params, cfg, tokens=tokens, mode="decode",
                                    cache=cache, pos=pos, pc=pc,
                                    extras=extras or {})
-        return logits[:, -1], cache
+        return last(logits), cache
 
     return prefill, decode
 

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 def quantize_int8(x: jax.Array):
     """Symmetric per-row int8 quantization. x: (..., d) fp -> (q, scale)."""

@@ -32,9 +32,9 @@ import numpy as np
 from repro.ckpt import CheckpointManager
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
 from repro.core import MonitoringStack
-from repro.core.line_protocol import now_ns
+from repro.core.perf_groups import check_device_peaks
 from repro.data import DataLoader, SyntheticTokenSource, make_batch_fn
-from repro.models.transformer import init_model_params, model_specs
+from repro.models.transformer import init_model_params
 from repro.train.optim import get_optimizer
 from repro.train.step import make_train_step
 
@@ -56,17 +56,15 @@ def compiled_step_constants(compiled, *, model_flops: float,
                             tokens_per_step: float) -> dict:
     """HPM step constants from one compiled step artifact.
 
-    ``cost_analysis_dict`` (XLA's own cost analysis) supplies flops/bytes
-    but reports nothing for collectives, so the collective operand/wire
-    bytes come from the trip-count-aware HLO walk (``analyze_hlo``) over
-    the same artifact — per device, matching the other constants.
+    ``compiled.cost_analysis()`` (XLA's own) supplies flops/bytes but
+    reports nothing for collectives, so the collective operand/wire bytes
+    come from the trip-count-aware HLO walk (``analyze_hlo``) over the same
+    artifact — per device, matching the other constants.  A failed walk
+    raises: zeroed constants would read as a job with no collectives.
     """
-    from repro.launch.hlo_analysis import analyze_hlo, cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
-    try:
-        per_dev = analyze_hlo(compiled.as_text())["per_device"]
-    except Exception:
-        per_dev = {}
+    from repro.launch.hlo_analysis import analyze_hlo
+    ca = compiled.cost_analysis()
+    per_dev = analyze_hlo(compiled.as_text())["per_device"]
     return {
         "hlo_flops": float(ca.get("flops", 0.0))
         or float(per_dev.get("flops", 0.0)),
@@ -88,7 +86,11 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
           step_callback: Optional[Callable] = None,
           user: str = "user", job_id: Optional[str] = None,
           markers: bool = True) -> TrainResult:
-    """Run (or resume) a monitored training job on the current devices."""
+    """Run (or resume) a monitored training job on the current devices.
+
+    ``in_shardings`` (the train bundle's, ``launch/steps.py``) places the
+    params and optimizer state on the mesh before the first step."""
+    check_device_peaks(jax.devices()[0])
     stack = stack or MonitoringStack.inprocess(out_dir="lms_out")
     hosts = hosts or [f"host{i}" for i in range(jax.process_count())]
     host = hosts[jax.process_index() % len(hosts)]
@@ -112,6 +114,9 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
             {"params": params, "opt_state": opt_state})
         params, opt_state = trees["params"], trees["opt_state"]
         resumed_from = start_step
+    if in_shardings is not None:
+        params, opt_state = jax.device_put((params, opt_state),
+                                           tuple(in_shardings[:2]))
 
     loader = DataLoader(batch_fn, global_batch=shape.global_batch,
                         start_step=start_step)
@@ -124,7 +129,7 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
 
     # ---- LMS wiring -------------------------------------------------------------
     tokens_per_step = shape.global_batch * shape.seq_len
-    model_flops = 6 * _active_params(model_cfg) * tokens_per_step
+    model_flops = 6 * model_cfg.active_param_count() * tokens_per_step
     agent = stack.host_agent(host)
     um = stack.usermetric(host=host)
     # marker regions (repro.core.marker): per-phase attribution of the
@@ -153,36 +158,33 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
                        tags={"arch": model_cfg.name, "shape": shape.name}):
             um.event("run_state", f"starting {model_cfg.name} at step "
                      f"{start_step}")
-            compiled_consts_set = False
+            compiled = not jit
             while step < train_cfg.total_steps:
                 step_idx, np_batch = next(loader)
                 data_wait = loader.wait_time_s
-                batch = {k: jax.numpy.asarray(v) for k, v in
-                         np_batch.items()}
-                if jit and not compiled_consts_set:
+                batch = jax.device_put(
+                    np_batch, None if in_shardings is None
+                    else in_shardings[2])
+                if not compiled:
                     # one-time (pre-execution, params still alive despite
-                    # donation): compiled-artifact HPM constants -> agent,
+                    # donation): compile the step once and run that
+                    # artifact, whose HPM constants go to the agent —
                     # including the real per-device collective operand /
-                    # wire bytes from the HLO walk (the seed hardcoded
-                    # collective_bytes=0.0 and starved the ICI group)
-                    try:
-                        consts = compiled_step_constants(
-                            train_step.lower(params, opt_state, batch,
-                                             step_idx).compile(),
-                            model_flops=model_flops,
-                            tokens_per_step=tokens_per_step)
-                    except Exception:
-                        consts = {"model_flops": model_flops,
-                                  "tokens_per_step": tokens_per_step}
+                    # wire bytes from the HLO walk
+                    train_step = train_step.lower(params, opt_state, batch,
+                                                  step_idx).compile()
+                    consts = compiled_step_constants(
+                        train_step, model_flops=model_flops,
+                        tokens_per_step=tokens_per_step)
                     agent.set_step_constants(**consts)
                     # static per-call work counters seeding the
                     # train_step marker region's roofline operands
                     step_counters = {
                         k: v for k, v in
-                        (("flops", consts.get("hlo_flops", 0.0)),
-                         ("bytes", consts.get("hlo_bytes", 0.0)))
-                        if v and v > 0.0}
-                    compiled_consts_set = True
+                        (("flops", consts["hlo_flops"]),
+                         ("bytes", consts["hlo_bytes"]))
+                        if v > 0.0}
+                    compiled = True
 
                 if mk:
                     mk.record("data_wait", data_wait)
@@ -243,13 +245,6 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
 
     return TrainResult(steps_run, step, last_loss, stack.findings(),
                        resumed_from)
-
-
-def _active_params(cfg: ModelConfig) -> int:
-    try:
-        return cfg.active_param_count()
-    except Exception:
-        return cfg.param_count()
 
 
 def _extras_fn(cfg: ModelConfig, shape: ShapeConfig):

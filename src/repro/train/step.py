@@ -22,7 +22,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.models.transformer import loss_fn

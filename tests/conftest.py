@@ -8,19 +8,8 @@ _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 if os.path.isdir(_SRC) and _SRC not in sys.path:
     sys.path.insert(0, os.path.abspath(_SRC))
 
-import jax
 import numpy as np
 import pytest
-
-# partial-manual shard_map (manual pipe/pod axis + auto data/model axes via
-# the ``auto``/``axis_names`` kwarg) hits a fatal XLA SPMD-partitioner check
-# (hlo_sharding_util: IsManualSubgroup) on JAX versions predating shard_map's
-# graduation to jax.shard_map — the subprocess dies with SIGABRT, nothing a
-# test can catch or work around in-process.
-needs_partial_manual_shard_map = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="partial-manual shard_map crashes XLA's SPMD partitioner on this "
-           "JAX version (IsManualSubgroup check failure)")
 
 
 @pytest.fixture

@@ -6,7 +6,6 @@ import numpy as np
 import pytest
 
 from repro.launch.hlo_analysis import (HloAnalyzer, analyze_hlo,
-                                       cost_analysis_dict,
                                        parse_computations)
 
 
@@ -37,7 +36,7 @@ def test_scan_trip_count_multiplies():
     assert res["per_device"]["flops"] >= 7 * 2 * 32**3
     assert res["per_device"]["flops"] < 1.3 * 7 * 2 * 32**3
     # vs. the uncorrected cost_analysis, which counts the body once
-    assert cost_analysis_dict(c)["flops"] < 2 * 2 * 32**3 + 5000
+    assert c.cost_analysis()["flops"] < 2 * 2 * 32**3 + 5000
 
 
 def test_nested_scan_trip_counts():
@@ -54,6 +53,24 @@ def test_nested_scan_trip_counts():
     c = _compile(f, jax.ShapeDtypeStruct((16, 16), jnp.float32))
     res = analyze_hlo(c.as_text())
     assert res["per_device"]["flops"] >= 15 * 2 * 16**3
+
+
+_KERNEL_HLO = """HloModule m
+
+ENTRY %main (x: bf16[256,4096]) -> bf16[256,4096] {
+  %x = bf16[256,4096]{1,0} parameter(0)
+  ROOT %k = bf16[256,4096]{1,0} custom-call(%x), custom_call_target="tpu_custom_call", backend_config={"custom_call_config":{"body":"TUzv","cost_estimate":{"flops":"12345","transcendentals":"7","bytes_accessed":"999","remote_bytes_transferred":"0"}}}
+}
+"""
+
+
+def test_kernel_custom_call_declared_cost():
+    """A Mosaic kernel is a custom call: the walk takes its flops from the
+    cost estimate the kernel declares, and its operand + result bytes."""
+    per = analyze_hlo(_KERNEL_HLO)["per_device"]
+    assert per["flops"] == 12345.0
+    assert per["transcendentals"] == 7.0
+    assert per["bytes"] == per["bytes_fused"] == 2 * 256 * 4096 * 2
 
 
 def test_bytes_reasonable():

@@ -4,14 +4,14 @@ dry-run artifact schema, and the mesh/config helpers."""
 import glob
 import json
 import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.configs import SHAPES, ShapeConfig, TrainConfig, get_config
-from repro.launch.hlo_analysis import cost_analysis_dict
-from repro.launch.mesh import make_mesh_for
+from repro.launch.mesh import make_mesh, make_mesh_for
 from repro.launch.steps import (build_bundle, build_decode_bundle,
                                 build_prefill_bundle, build_train_bundle,
                                 input_specs, lower_bundle)
@@ -24,7 +24,7 @@ TINY_DECODE = ShapeConfig("tinyd", seq_len=32, global_batch=2, kind="decode")
 
 @pytest.fixture(scope="module")
 def mesh1():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", ["granite-3-8b", "mixtral-8x7b",
@@ -38,7 +38,7 @@ def test_bundles_lower_and_compile(arch, mesh1):
         bundle = build_bundle(cfg, shape, mesh1,
                               train_cfg=TrainConfig(num_microbatches=2))
         compiled = lower_bundle(bundle, mesh1).compile()
-        assert cost_analysis_dict(compiled).get("flops", 0) > 0
+        assert compiled.cost_analysis()["flops"] > 0
         mem = compiled.memory_analysis()
         assert mem.temp_size_in_bytes >= 0
 
@@ -59,6 +59,32 @@ def test_make_mesh_for_elastic():
     m = make_mesh_for(1)
     assert m.devices.size == 1
     assert m.axis_names == ("data", "model")
+
+
+def test_make_mesh_axes_are_auto():
+    """with_sharding_constraint accepts Auto axes only."""
+    from jax.sharding import AxisType
+    assert make_mesh((1, 1), ("data", "model")).axis_types == \
+        (AxisType.Auto, AxisType.Auto)
+    assert set(make_mesh_for(1).axis_types) == {AxisType.Auto}
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    cache is the fixed <repo>/.jax_cache."""
+    from repro.launch.compile_cache import (REPO_CACHE_DIR,
+                                            enable_compile_cache)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert enable_compile_cache() == str(REPO_CACHE_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
+        assert REPO_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_dryrun_artifacts_schema():

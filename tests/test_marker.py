@@ -495,6 +495,37 @@ ENTRY %main (p: f32[8]) -> f32[8] {
     assert consts["wire_bytes"] == 0.0
 
 
+def test_compiled_step_constants_walk_failure_propagates():
+    """A failed HLO walk fails the job instead of zeroing the constants
+    (zeroed collective_bytes reads as a job without collectives)."""
+    from repro.train.loop import compiled_step_constants
+
+    class _Broken(_StubCompiled):
+        def as_text(self):
+            raise RuntimeError("no HLO text")
+
+    with pytest.raises(RuntimeError, match="no HLO text"):
+        compiled_step_constants(_Broken(), model_flops=1.0,
+                                tokens_per_step=1.0)
+
+
+@pytest.mark.parametrize("platform,kind,ok", [
+    ("tpu", "TPU v5 lite", True),
+    ("tpu", "TPU v4", False),
+    ("cpu", "cpu", True),
+])
+def test_check_device_peaks(platform, kind, ok):
+    """The peaks are the v5e's; a job on another TPU kind is refused."""
+    from types import SimpleNamespace
+    from repro.core.perf_groups import check_device_peaks
+    dev = SimpleNamespace(platform=platform, device_kind=kind)
+    if ok:
+        check_device_peaks(dev)
+    else:
+        with pytest.raises(ValueError, match="TPU v4"):
+            check_device_peaks(dev)
+
+
 def test_serving_engine_request_phase_regions(tmp_path):
     np = pytest.importorskip("numpy")
     from repro.configs import get_config

@@ -27,13 +27,14 @@ def test_a2a_dispatch_matches_reference():
         from repro.configs import get_config
         from repro.models.moe import apply_moe, apply_moe_a2a, moe_specs
         from repro.models.params import init_params
+        from repro.launch.mesh import make_mesh
 
         cfg = get_config("mixtral-8x7b", smoke=True)
         # generous capacity so neither path drops tokens -> exact parity
         cfg.moe = dataclasses.replace(cfg.moe, num_experts=8,
                                       capacity_factor=8.0)
         params = init_params(moe_specs(cfg), seed=0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         b, s = 4, 16
         x = 0.1 * jnp.asarray(
             np.random.default_rng(0).standard_normal((b, s, cfg.d_model)),
@@ -63,12 +64,13 @@ def test_a2a_dispatch_differentiable():
         from repro.configs import get_config
         from repro.models.moe import apply_moe, apply_moe_a2a, moe_specs
         from repro.models.params import init_params
+        from repro.launch.mesh import make_mesh
 
         cfg = get_config("mixtral-8x7b", smoke=True)
         cfg.moe = dataclasses.replace(cfg.moe, num_experts=8,
                                       capacity_factor=8.0)
         params = init_params(moe_specs(cfg), seed=0)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         x = 0.1 * jnp.asarray(
             np.random.default_rng(1).standard_normal((4, 16, cfg.d_model)),
             jnp.float32)

@@ -8,8 +8,6 @@ import textwrap
 
 import pytest
 
-from conftest import needs_partial_manual_shard_map
-
 _SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
@@ -77,10 +75,11 @@ def test_compressed_pmean_shard_map():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
+        from repro.launch.mesh import make_mesh
         from repro.train.compression import compressed_pmean
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 4, 16)),
                         jnp.float32)
 
@@ -97,13 +96,13 @@ def test_compressed_pmean_shard_map():
     """)
 
 
-@needs_partial_manual_shard_map
 def test_cross_pod_compressed_train_step():
     """Full train step with hierarchical pod-axis int8 gradient sync (manual
     pod axis + auto data/model axes) compiles and runs."""
     _run("""
         import jax, jax.numpy as jnp
         from repro.configs import get_config, TrainConfig, ShapeConfig
+        from repro.launch.mesh import make_mesh
         from repro.train.step import make_train_step
         from repro.train.optim import get_optimizer
         from repro.models.transformer import init_model_params
@@ -113,7 +112,7 @@ def test_cross_pod_compressed_train_step():
         cfg = get_config("lms-demo", smoke=True)
         tcfg = TrainConfig(grad_compression="int8", learning_rate=1e-3,
                            warmup_steps=1)
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         # inside the manual-pod region the constraints must not name "pod"
         pc = PartitionConstraints(TRAIN_RULES.with_overrides(
             batch=("data",)), mesh)
@@ -142,7 +141,7 @@ def test_elastic_restart_smaller_mesh(tmp_path):
         from repro.models.transformer import init_model_params, model_specs
         from repro.parallel.sharding import shardings_for_specs, TRAIN_RULES
         from repro.ckpt import save_checkpoint, load_checkpoint
-        from repro.launch.mesh import make_mesh_for
+        from repro.launch.mesh import make_mesh, make_mesh_for
 
         cfg = get_config("lms-demo", smoke=True)
         params = init_model_params(cfg, 0)
@@ -152,8 +151,8 @@ def test_elastic_restart_smaller_mesh(tmp_path):
         save_checkpoint({str(tmp_path)!r}, 3, {{"params": params}})
 
         # "failure": restart with only 4 devices
-        mesh4 = jax.make_mesh((2, 2), ("data", "model"),
-                              devices=jax.devices()[:4])
+        mesh4 = make_mesh((2, 2), ("data", "model"),
+                          devices=jax.devices()[:4])
         sh4 = shardings_for_specs(model_specs(cfg), TRAIN_RULES, mesh4)
         step, out = load_checkpoint({str(tmp_path)!r},
                                     {{"params": params}},

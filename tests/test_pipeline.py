@@ -6,8 +6,6 @@ import subprocess
 import sys
 import textwrap
 
-from conftest import needs_partial_manual_shard_map
-
 _SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
@@ -25,9 +23,10 @@ def _run(code: str) -> str:
 def test_pipeline_matches_sequential():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import make_mesh
         from repro.parallel.pipeline import pipeline_apply, bubble_fraction
 
-        mesh = jax.make_mesh((4,), ("pipe",))
+        mesh = make_mesh((4,), ("pipe",))
         S, B, D = 4, 8, 16
         rng = np.random.default_rng(0)
         ws = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)
@@ -55,14 +54,14 @@ def test_pipeline_matches_sequential():
     assert "PIPELINE OK" in out
 
 
-@needs_partial_manual_shard_map
 def test_pipeline_composes_with_data_axis():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.launch.mesh import make_mesh
         from repro.parallel.pipeline import pipeline_apply
 
-        mesh = jax.make_mesh((2, 4), ("data", "pipe"))
+        mesh = make_mesh((2, 4), ("data", "pipe"))
         S, B, D = 4, 8, 16
         rng = np.random.default_rng(1)
         ws = jnp.asarray(rng.standard_normal((S, D, D)) * 0.3, jnp.float32)

@@ -165,7 +165,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
     """Load with explicit (single-device) shardings — the elastic path."""
     d = str(tmp_path / "ck")
     save_checkpoint(d, 7, _trees(3.0))
-    mesh = jax.make_mesh((1,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("data",))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = jax.tree.map(lambda _: NamedSharding(mesh, P()), _trees()["params"])
     step, out = load_checkpoint(d, {"params": _trees()["params"]},

@@ -1,0 +1,105 @@
+"""Compiles for a described TPU v5e (no chip needed): the Pallas kernels at
+real widths, and the granite-3-8b train step that ``chip_smoke.py`` runs.
+
+The TPU compiler refuses what interpret mode accepts: block shapes off the
+(8, 128) tiling, kernels over the scoped VMEM limit, programs larger than
+the chip's memory.  The topology is described inside a module fixture —
+only one process at a time may load the TPU library, so it must never be
+touched while a module is imported.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import ShapeConfig, TrainConfig, get_config
+from repro.kernels import flash_attention as fa
+from repro.kernels import rmsnorm as rms
+from repro.kernels import ssd
+from repro.launch.hlo_analysis import analyze_hlo
+from repro.launch.steps import train_input_specs
+from repro.models.params import abstract_params
+from repro.models.transformer import model_specs
+from repro.train.optim import opt_state_specs
+from repro.train.step import make_train_step
+
+V5E_HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _sds(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _kernel_text(lowered) -> str:
+    text = lowered.compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_compiles(one_chip, dtype):
+    """granite-3-8b attention: 32 query heads over 8 KV heads of 128."""
+    q = _sds(one_chip, (1, 32, 2048, 128), dtype)
+    kv = _sds(one_chip, (1, 8, 2048, 128), dtype)
+    text = _kernel_text(fa.flash_attention.lower(q, kv, kv))
+    # the walk reads the flops the kernel declares to the compiler
+    want = fa.cost_estimate(q.shape, 8, jnp.dtype(dtype).itemsize)
+    assert analyze_hlo(text)["per_device"]["flops"] == want["flops"]
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_rmsnorm_compiles(one_chip, dtype):
+    """d_model 4096; fp32 rows once overflowed the scoped VMEM."""
+    _kernel_text(rms.rmsnorm.lower(_sds(one_chip, (4096, 4096), dtype),
+                                   _sds(one_chip, (4096,), dtype)))
+
+
+def test_ssd_scan_compiles(one_chip):
+    """zamba2-7b SSD widths: head dim 64, state 64, chunk 128."""
+    f32 = jnp.float32
+    x = _sds(one_chip, (1, 8, 2048, 64), f32)
+    a = _sds(one_chip, (1, 8, 2048), f32)
+    bc = _sds(one_chip, (1, 8, 2048, 64), f32)
+    _kernel_text(ssd.ssd_scan.lower(x, a, bc, bc, chunk=128))
+
+
+def test_granite_train_step_fits_one_chip(one_chip):
+    """The smoke's monitored train step: granite-3-8b at published widths,
+    2 layers, B=2, S=2048, fp32 params + AdamW — arguments plus
+    temporaries within one v5e's 16 GiB."""
+    cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=2)
+    tcfg = TrainConfig()
+    shape = ShapeConfig("smoke", seq_len=2048, global_batch=2, kind="train")
+
+    def sds(tree):
+        return jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                            abstract_params(tree))
+
+    pspecs = model_specs(cfg)
+    args = (sds(pspecs), sds(opt_state_specs(pspecs, tcfg)),
+            sds(train_input_specs(cfg, shape)),
+            _sds(one_chip, (), jnp.int32))
+    step, _ = make_train_step(cfg, tcfg)
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert 0 < used < V5E_HBM_BYTES, used
+    assert compiled.cost_analysis()["flops"] > 0

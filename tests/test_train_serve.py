@@ -111,6 +111,30 @@ def test_serving_greedy_consistency():
     assert out == toks
 
 
+def test_serving_masks_vocab_padding():
+    """Ids in the padded vocab tail are not tokens: the serve steps score
+    them -inf, so greedy decoding never emits one."""
+    import dataclasses
+    from repro.models.transformer import init_cache
+    from repro.serve.engine import make_serve_fns
+
+    cfg = dataclasses.replace(get_config("lms-demo", smoke=True),
+                              vocab_size=500)
+    assert cfg.vocab_padded == 512
+    params = init_model_params(cfg, seed=0)
+    prefill, _ = make_serve_fns(cfg)
+    logits, _ = prefill(params, jnp.arange(1, 9, dtype=jnp.int32)[None],
+                        init_cache(cfg, 1, 32))
+    assert bool(jnp.all(jnp.isneginf(logits[:, cfg.vocab_size:])))
+    assert bool(jnp.all(jnp.isfinite(logits[:, :cfg.vocab_size])))
+
+    eng = ServingEngine(cfg, params, max_batch=2, max_len=32, jit=False)
+    for n in (5, 8):
+        eng.submit(np.arange(1, n), max_new_tokens=4)
+    done = eng.run_until_empty()
+    assert all(0 <= t < cfg.vocab_size for r in done for t in r.output)
+
+
 def test_straggler_finding_triggers_elastic_halt(tmp_path):
     """Monitoring is load-bearing: a sustained straggler finding (emitted by
     a simulated peer host) halts the loop so the launcher can restart
