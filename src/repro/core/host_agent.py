@@ -20,7 +20,10 @@ import resource
 import socket
 import threading
 import time
+from functools import partial
 from typing import Optional
+
+from jax.profiler import annotate_function
 
 from repro.core.line_protocol import Point, now_ns
 from repro.core.perf_groups import derive_all
@@ -159,6 +162,7 @@ class HostAgent:
 
     # -- per-step HPM ------------------------------------------------------------
 
+    @partial(annotate_function, name="lms.agent.collect_step")
     def collect_step(self, *, step: int, step_time_s: float,
                      extra_events: Optional[dict] = None,
                      emit: bool = True, ts: Optional[int] = None) -> dict:

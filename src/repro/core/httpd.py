@@ -84,6 +84,8 @@ import urllib.request
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.analysis import Alert, load_alerts, load_job_report
 from repro.core.line_protocol import Point, encode_batch
 from repro.core.router import MetricsRouter
@@ -365,6 +367,10 @@ class LMSRequestHandler(BaseHTTPRequestHandler):
 
     def do_POST(self):
         url = urllib.parse.urlparse(self.path)
+        with TraceAnnotation("lms.http.post", path=url.path):
+            self._do_post(url)
+
+    def _do_post(self, url):
         try:
             body = self._body()
         except _PayloadTooLarge as e:

@@ -43,7 +43,10 @@ from __future__ import annotations
 
 import threading
 import time
+from functools import partial
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.line_protocol import now_ns
 from repro.core.perf_groups import (formula_for, register_group,
@@ -63,15 +66,18 @@ CALIB_REGION = "_calib"
 
 
 class _Frame:
-    """One open region on one thread's stack."""
+    """One open region on one thread's stack, with its ``marker.<name>``
+    span (open from start to stop)."""
 
-    __slots__ = ("name", "t0", "child_s", "counters")
+    __slots__ = ("name", "t0", "child_s", "counters", "span")
 
     def __init__(self, name: str, t0: float):
         self.name = name
         self.t0 = t0
         self.child_s = 0.0          # inclusive seconds of finished children
         self.counters = None
+        self.span = TraceAnnotation(f"marker.{name}")
+        self.span.__enter__()
 
 
 class Region:
@@ -186,6 +192,7 @@ class MarkerSession:
 
     def _pop(self, st: list, now: float, counters: Optional[dict]) -> float:
         fr = st.pop()
+        fr.span.__exit__(None, None, None)
         incl = max(now - fr.t0, 0.0)
         excl = max(incl - fr.child_s, 0.0)
         if st:
@@ -253,6 +260,7 @@ class MarkerSession:
 
     # -- emission -------------------------------------------------------------
 
+    @partial(annotate_function, name="lms.marker.flush")
     def flush(self, ts: Optional[int] = None) -> dict:
         """Drain pending deltas; emit one ``marker`` point per region (all
         points of one flush share one timestamp, so cross-region queries

@@ -52,6 +52,8 @@ from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Iterable, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.perf_groups import (HW_CONSTANTS, CompiledFormula,
                                     compile_formula, formula_for)
 from repro.core.rollup import ROLLUP_AGGS, known_agg, quantile_of
@@ -674,24 +676,28 @@ class QueryEngine:
             return None
 
     def query(self, spec: QuerySpec) -> QueryResult:
-        plan = self.plan(spec)
-        self.stats["queries"] += 1
-        wm = self._watermark(plan)
-        if wm is not None:
-            hit = self._cache.get((plan.fingerprint, wm))
-            if hit is not None:
-                self.stats["cache_hits"] += 1
-                return hit
-        self.stats["cache_misses"] += 1
-        collected = self.collect(spec)
-        res = evaluate_plan(plan, collected)
-        # advisory: which storage tiers the collection actually spanned
-        # (never part of to_json(), so parity comparisons are unaffected)
-        res.meta["tiers"] = plan_tiers(plan, self.backend)
-        if wm is not None:
-            res.meta["watermark"] = list(wm)
-            self._cache.put((plan.fingerprint, wm), res)
-        return res
+        with TraceAnnotation("lms.query.exec") as sp:
+            plan = self.plan(spec)
+            self.stats["queries"] += 1
+            wm = self._watermark(plan)
+            if wm is not None:
+                hit = self._cache.get((plan.fingerprint, wm))
+                if hit is not None:
+                    self.stats["cache_hits"] += 1
+                    sp.set_metadata(cache="hit")
+                    return hit
+            self.stats["cache_misses"] += 1
+            sp.set_metadata(cache="miss")
+            collected = self.collect(spec)
+            res = evaluate_plan(plan, collected)
+            # advisory: which storage tiers the collection actually spanned
+            # (never part of to_json(), so parity comparisons are
+            # unaffected)
+            res.meta["tiers"] = plan_tiers(plan, self.backend)
+            if wm is not None:
+                res.meta["watermark"] = list(wm)
+                self._cache.put((plan.fingerprint, wm), res)
+            return res
 
     def collect(self, spec: QuerySpec) -> dict:
         """Merged per-input partials for a spec (the mergeable half —

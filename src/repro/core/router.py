@@ -19,7 +19,10 @@ from __future__ import annotations
 
 import threading
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Callable, Iterable, Optional, Union
+
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core.jobs import JobRegistry
 from repro.core.line_protocol import (Point, decode_batch_errors,
@@ -110,6 +113,7 @@ class MetricsRouter:
             if fn in self._subs:
                 self._subs.remove(fn)
 
+    @partial(annotate_function, name="lms.router.publish")
     def _publish(self, kind: str, payload):
         with self._lock:
             subs = list(self._subs)
@@ -166,6 +170,10 @@ class MetricsRouter:
             points = [points]
         elif not isinstance(points, (list, tuple)):
             points = list(points)
+        with TraceAnnotation("lms.router.write", points=len(points)):
+            self._write(points)
+
+    def _write(self, points: list):
         # batch fast path: the tag-store lookup (a lock per call) is done
         # once per distinct host in the batch, not once per point
         host_tags: dict = {}

@@ -16,7 +16,10 @@ from __future__ import annotations
 import socket
 import threading
 import time
+from functools import partial
 from typing import Callable, Optional, Union
+
+from jax.profiler import annotate_function
 
 from repro.core.line_protocol import Point, now_ns
 
@@ -61,6 +64,7 @@ class UserMetric:
 
     # -- emit -----------------------------------------------------------------
 
+    @partial(annotate_function, name="lms.usermetric.metric")
     def metric(self, name: str, value: Union[float, int, dict],
                tags: Optional[dict] = None, ts: Optional[int] = None):
         """Numeric metric; ``value`` may be a dict of field -> value."""
@@ -138,6 +142,7 @@ class UserMetric:
             # are counted and the points re-buffered (bounded) instead
             self._flush(raise_errors=False)
 
+    @partial(annotate_function, name="lms.usermetric.flush")
     def flush(self):
         """Explicit flush: sink failures re-buffer AND raise, so batch
         scripts that call ``flush()``/``close()`` see the error.  Pending

@@ -22,7 +22,13 @@ def enable_compile_cache() -> str:
 
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
     nothing is set here; otherwise the cache goes to ``<repo>/.jax_cache``.
+
+    Cache keys include the program's metadata (named scopes, files, lines).
+    By default JAX strips it, so an executable loaded from the cache keeps
+    the op names of whichever build first compiled the same HLO, and a
+    profile then attributes device time to that build's scopes.
     """
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env:
         return env

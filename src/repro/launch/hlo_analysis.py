@@ -5,8 +5,10 @@ EXPERIMENTS.md §Dry-run), which under-reports scanned-layer models by ~num
 layers; and it reports nothing about collectives.  This walker parses
 ``compiled.as_text()`` (the post-SPMD, per-partition module) and computes:
 
-* ``flops``       — dot/elementwise/reduce FLOPs, **x while trip counts**
-                    (XLA annotates ``known_trip_count`` on scan loops);
+* ``flops``       — dot/convolution/elementwise/reduce FLOPs, **x while
+                    trip counts** (``known_trip_count`` where XLA annotates
+                    it; else read off the loop's condition, as the TPU
+                    backend leaves it out);
 * ``bytes``       — fusion-boundary traffic (operands+outputs of top-level
                     ops; fusion internals excluded, matching XLA's model);
 * ``collective_bytes`` — assignment definition: sum of *operand* sizes of
@@ -41,6 +43,9 @@ _INSTR_RE = re.compile(
     r"^\s*(?:ROOT\s+)?%?([\w\.\-]+)\s*=\s*(.*?)\s+([a-z][a-z0-9\-]*)\(")
 _COMP_RE = re.compile(r"^(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->")
 _TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_CONST_RE = re.compile(r"constant\((-?\d+)\)")
+_GTE_INDEX_RE = re.compile(r"index=(\d+)")
+_DIRECTION_RE = re.compile(r"direction=(\w+)")
 _CALLS_RE = re.compile(r"calls=%?([\w\.\-]+)")
 _BODY_RE = re.compile(r"body=%?([\w\.\-]+)")
 _COND_RE = re.compile(r"condition=%?([\w\.\-]+)")
@@ -48,6 +53,8 @@ _GROUPS_V2_RE = re.compile(r"replica_groups=\[(\d+),(\d+)\]")
 _GROUPS_V1_RE = re.compile(r"replica_groups=\{\{([0-9, ]*)\}")
 _LHS_CONTRACT_RE = re.compile(r"lhs_contracting_dims=\{([0-9,]*)\}")
 _OPERAND_RE = re.compile(r"%([\w\.\-]+)")
+_WINDOW_RE = re.compile(r"window=\{([^}]*)\}")
+_DIM_LABELS_RE = re.compile(r"dim_labels=(\w+)_(\w+)->(\w+)")
 # a Pallas kernel's declared ``pl.CostEstimate``, as the TPU backend embeds
 # it in the ``tpu_custom_call`` backend config
 _KERNEL_COST_RE = re.compile(
@@ -203,13 +210,68 @@ def _dot_flops(instr: Instr, shapes: dict) -> float:
     return 2.0 * out_elems * contract
 
 
+def _valid_taps(n_in: int, n_out: int, k: int, stride: int, pad_lo: int,
+                lhs_dilate: int, rhs_dilate: int) -> int:
+    """(output position, kernel tap) pairs of one spatial dimension that
+    land on a real input element: not padding, not a hole of the input's
+    dilation."""
+    last = (n_in - 1) * lhs_dilate
+    count = 0
+    for tap in range(k):
+        c = tap * rhs_dilate - pad_lo
+        for o in range(n_out):
+            pos = o * stride + c
+            if 0 <= pos <= last and pos % lhs_dilate == 0:
+                count += 1
+    return count
+
+
+def _conv_flops(instr: Instr, shapes: dict) -> float:
+    """2 x the multiply-adds of a convolution: for each output batch and
+    feature element, the kernel's input features over every valid tap.
+    The TPU backend lowers every matmul to one; a batched matmul becomes a
+    convolution over the batch dims with ``lhs_dilate`` = window size, of
+    which one tap per output position is real."""
+    labels = _DIM_LABELS_RE.search(instr.line)
+    ops = _OPERAND_RE.findall(instr.line.split("(", 1)[1])
+    if not labels or len(ops) < 2:
+        return 0.0
+    lhs_l, rhs_l, out_l = labels.groups()
+    lhs, rhs = shapes.get(ops[0], []), shapes.get(ops[1], [])
+    out = _shape_dims(instr.type_str)
+    if (len(lhs), len(rhs), len(out)) != (len(lhs_l), len(rhs_l),
+                                          len(out_l)):
+        return 0.0
+    win = {}
+    w = _WINDOW_RE.search(instr.line)
+    for field in (w.group(1).split() if w else []):
+        key, _, val = field.partition("=")
+        win[key] = val.split("x")
+    macs = out[out_l.index("b")] * out[out_l.index("f")] \
+        * rhs[rhs_l.index("i")]
+    for i in range(len(out_l) - 2):
+        d = str(i)
+
+        def attr(key, default, i=i):
+            vals = win.get(key)
+            return vals[i] if vals else default
+        pad_lo = int(attr("pad", "0_0").split("_")[0])
+        macs *= _valid_taps(lhs[lhs_l.index(d)], out[out_l.index(d)],
+                            rhs[rhs_l.index(d)], int(attr("stride", "1")),
+                            pad_lo, int(attr("lhs_dilate", "1")),
+                            int(attr("rhs_dilate", "1")))
+    return 2.0 * macs
+
+
 class HloAnalyzer:
     def __init__(self, hlo_text: str):
         self.comps, self.num_partitions = parse_computations(hlo_text)
         self._shapes: dict = {}
+        self._instrs: dict = {}
         for instrs in self.comps.values():
             for i in instrs:
                 self._shapes[i.name] = _shape_dims(i.type_str)
+                self._instrs[i.name] = i
         self._memo: dict = {}
         self.trip_counts: dict = {}
 
@@ -356,6 +418,22 @@ class HloAnalyzer:
             return total
         return out_bytes
 
+    def _passes_through(self, comp_name: str, index: int) -> bool:
+        """Parameter ``index`` reaches the computation's root through
+        single-consumer unary wrappers only."""
+        instrs = self.comps.get(comp_name, [])
+        cur = next((i for i in instrs if i.opcode == "parameter" and
+                    f"parameter({index})" in i.line), None)
+        while cur is not None:
+            if cur.line.startswith("ROOT"):
+                return True
+            users = [i for i in instrs if cur.name in
+                     _OPERAND_RE.findall(self._operand_text(i.line))]
+            if len(users) != 1 or users[0].opcode not in _UNARY_THRU:
+                return False
+            cur = users[0]
+        return False
+
     def _param_usage(self, comp_name: str) -> dict:
         """param index -> effective bytes (None = read fully)."""
         if not hasattr(self, "_param_usage_cache"):
@@ -397,6 +475,20 @@ class HloAnalyzer:
                     if sub is None:
                         return None
                     total += sub
+                elif c.opcode == "fusion" and _CALLS_RE.search(c.line):
+                    # a nested fusion (the TPU backend's slice inside the
+                    # matmul's fusion) reads what its parameter reads; one
+                    # that only passes it on (a bitcast) reads what its
+                    # consumers here read
+                    called = _CALLS_RE.search(c.line).group(1)
+                    inner = self._param_usage(called)
+                    for k in (k for k, o in enumerate(ops) if o == name):
+                        sub = eff_bytes(c.name, depth + 1) \
+                            if self._passes_through(called, k) \
+                            else inner.get(k)
+                        if sub is None:
+                            return None
+                        total += sub
                 else:
                     return None
             return total
@@ -427,12 +519,11 @@ class HloAnalyzer:
         out_elems = _shape_elems(instr.type_str)
 
         if op == "while":
-            trip = 1.0
-            m = _TRIP_RE.search(instr.line)
-            if m:
-                trip = float(m.group(1))
             body = _BODY_RE.search(instr.line)
             cond = _COND_RE.search(instr.line)
+            m = _TRIP_RE.search(instr.line)
+            trip = float(m.group(1)) if m else \
+                self._trip_from_condition(instr, cond and cond.group(1))
             inner = HloCost()
             if body:
                 inner.add(self._comp_cost(body.group(1)))
@@ -514,6 +605,8 @@ class HloAnalyzer:
                 hbm_real = True
         elif op == "dot":
             cost.flops += _dot_flops(instr, self._shapes)
+        elif op == "convolution":
+            cost.flops += _conv_flops(instr, self._shapes)
         elif op in ("reduce", "reduce-window"):
             cost.flops += self._operand_elems_first(instr)
         elif op == "sort":
@@ -545,6 +638,50 @@ class HloAnalyzer:
                 elif op == "scatter":
                     io = 3 * self._update_operand_bytes(instr)
                 cost.bytes_fused += io
+
+    def _operands(self, instr: Instr) -> list:
+        return _OPERAND_RE.findall(self._operand_text(instr.line))
+
+    def _int_constant(self, name: str):
+        """The integer a value holds, followed through copies and
+        bitcasts to its ``constant``; ``None`` if it is not one."""
+        i = self._instrs.get(name)
+        while i is not None and i.opcode in ("copy", "bitcast"):
+            ops = self._operands(i)
+            i = self._instrs.get(ops[0]) if ops else None
+        if i is None or i.opcode != "constant":
+            return None
+        m = _CONST_RE.search(i.line)
+        return int(m.group(1)) if m else None
+
+    def _trip_from_condition(self, loop: Instr, cond: Optional[str]) -> float:
+        """A counted loop's trips from ``counter < N`` (or ``<=``) at the
+        root of its condition, the counter's start read from the loop's
+        initial tuple (0 if it is not a constant) and a step of 1, as a
+        ``scan`` makes it; 1 for any other loop."""
+        instrs = self.comps.get(cond or "", [])
+        root = next((i for i in instrs if i.line.startswith("ROOT")), None)
+        if root is None or root.opcode != "compare":
+            return 1.0
+        direction = _DIRECTION_RE.search(root.line)
+        ops = self._operands(root)
+        if not direction or len(ops) != 2 or \
+                direction.group(1) not in ("LT", "LE"):
+            return 1.0
+        counter = self._instrs.get(ops[0])
+        limit = self._int_constant(ops[1])
+        if counter is None or counter.opcode != "get-tuple-element" or \
+                limit is None:
+            return 1.0
+        index = int(_GTE_INDEX_RE.search(counter.line).group(1))
+        start = 0
+        init = self._instrs.get((self._operands(loop) or [""])[0])
+        if init is not None and init.opcode == "tuple":
+            elems = self._operands(init)
+            if index < len(elems):
+                start = self._int_constant(elems[index]) or 0
+        trips = limit - start + (direction.group(1) == "LE")
+        return float(max(trips, 0))
 
     def _operand_elems_first(self, instr: Instr) -> int:
         ops = _OPERAND_RE.findall(self._operand_text(instr.line))

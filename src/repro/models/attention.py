@@ -286,6 +286,7 @@ def _cache_write(cache_arr, new, slot, cache_update: str):
     return onehot_update(cache_arr, new, slot)
 
 
+@jax.named_scope("attention")
 def gqa_attention(p, x, cfg: ModelConfig, *, rope=None, mode="train",
                   cache=None, pos=None, attn_impl="masked",
                   kv_out_constraint=None, bidirectional=False,
@@ -375,6 +376,7 @@ def gqa_attention(p, x, cfg: ModelConfig, *, rope=None, mode="train",
     return y, new_cache
 
 
+@jax.named_scope("attention")
 def cross_attention(p, x, kv_cache, cfg: ModelConfig):
     """Decoder cross-attention against precomputed encoder K/V."""
     dt = x.dtype
@@ -396,6 +398,7 @@ def cross_kv(p, enc_out, cfg: ModelConfig):
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("attention")
 def mla_attention(p, x, cfg: ModelConfig, *, rope, mode="train", cache=None,
                   pos=None, attn_impl="masked", cache_update="onehot"):
     """Multi-head Latent Attention.

@@ -29,6 +29,7 @@ def norm_specs(cfg: ModelConfig, d: Optional[int] = None):
     return {"scale": spec((d,), ("norm",), init="ones")}
 
 
+@jax.named_scope("norm")
 def apply_norm(p, x, cfg: ModelConfig, eps: Optional[float] = None):
     eps = eps or cfg.norm_eps
     xf = x.astype(jnp.float32)
@@ -43,6 +44,7 @@ def apply_norm(p, x, cfg: ModelConfig, eps: Optional[float] = None):
     return y.astype(x.dtype)
 
 
+@jax.named_scope("norm")
 def rmsnorm_gated(scale, x, gate, eps: float = 1e-5):
     """Mamba2-style gated RMSNorm: norm(x * silu(gate)) * scale."""
     x = x * jax.nn.silu(gate.astype(jnp.float32)).astype(x.dtype)
@@ -127,6 +129,7 @@ def mlp_specs(cfg: ModelConfig, d_ff: Optional[int] = None):
     }
 
 
+@jax.named_scope("mlp")
 def apply_mlp(p, x, cfg: ModelConfig):
     dt = x.dtype
     if cfg.mlp_type == "swiglu":
@@ -155,10 +158,12 @@ def embedding_specs(cfg: ModelConfig):
     return out
 
 
+@jax.named_scope("embed")
 def embed_tokens(p, tokens, cfg: ModelConfig):
     return p["embedding"].astype(jnp.dtype(cfg.dtype))[tokens]
 
 
+@jax.named_scope("logits_loss")
 def lm_logits(p, h, cfg: ModelConfig):
     if cfg.tie_embeddings:
         w = p["embedding"].astype(h.dtype).T

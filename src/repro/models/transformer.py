@@ -294,6 +294,7 @@ def _combine_aux(acc, aux):
     }
 
 
+@jax.named_scope("attention")
 def _rope_for(cfg: ModelConfig, positions, extras):
     if cfg.rope_type == "none":
         return None
@@ -582,9 +583,10 @@ def loss_fn(params, cfg: ModelConfig, batch, *, pc=None, attn_impl="masked",
                              mode="train", pc=pc, extras=extras,
                              attn_impl=attn_impl, remat=remat,
                              scan_unroll=scan_unroll)
-    mask = (batch["labels"] >= 0)
-    labels = jnp.maximum(batch["labels"], 0)
-    loss = cross_entropy(logits, labels, cfg, mask=mask)
+    with jax.named_scope("logits_loss"):
+        mask = (batch["labels"] >= 0)
+        labels = jnp.maximum(batch["labels"], 0)
+        loss = cross_entropy(logits, labels, cfg, mask=mask)
     total = loss
     if cfg.moe is not None:
         total = total + 0.01 * aux["moe_aux_loss"] / max(cfg.num_layers, 1)

@@ -4,8 +4,8 @@ The loop is a *job* in the LMS sense (DESIGN.md §4):
 
 * job start/end signals bracket the run (router tag store tags every metric);
 * one :class:`HostAgent` per host emits the XLA-derived HPM metrics each
-  step (FLOPs/bytes/collective counters come from the compiled step's cost
-  analysis, set once after compile);
+  step (FLOPs/bytes/collective counters come from a walk of the compiled
+  step's HLO, set once after compile);
 * ``libusermetric`` carries application-level series (loss, grad norm,
   tokens/s — the paper's Fig. 3 analogue) and events (checkpoint saved,
   restart, failure injected);
@@ -28,6 +28,7 @@ from typing import Callable, Optional
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.ckpt import CheckpointManager
 from repro.configs.base import ModelConfig, ShapeConfig, TrainConfig
@@ -56,23 +57,21 @@ def compiled_step_constants(compiled, *, model_flops: float,
                             tokens_per_step: float) -> dict:
     """HPM step constants from one compiled step artifact.
 
-    ``compiled.cost_analysis()`` (XLA's own) supplies flops/bytes but
-    reports nothing for collectives, so the collective operand/wire bytes
-    come from the trip-count-aware HLO walk (``analyze_hlo``) over the same
-    artifact — per device, matching the other constants.  A failed walk
-    raises: zeroed constants would read as a job with no collectives.
+    Every count comes from the trip-count-aware HLO walk (``analyze_hlo``)
+    over ``compiled.as_text()``, per device: XLA's own ``cost_analysis()``
+    counts a scanned layer body once and reports nothing for collectives.
+    ``hlo_bytes`` is the walk's in-place model (``bytes_fused``): a slice
+    of a layer-stacked buffer moves the slice, not the whole stack.  A
+    failed walk raises: zeroed constants would read as a job that does no
+    work and has no collectives.
     """
     from repro.launch.hlo_analysis import analyze_hlo
-    ca = compiled.cost_analysis()
     per_dev = analyze_hlo(compiled.as_text())["per_device"]
     return {
-        "hlo_flops": float(ca.get("flops", 0.0))
-        or float(per_dev.get("flops", 0.0)),
-        "hlo_bytes": float(ca.get("bytes accessed", 0.0))
-        or float(per_dev.get("bytes", 0.0)),
-        "collective_bytes": float(
-            per_dev.get("collective_operand_bytes", 0.0)),
-        "wire_bytes": float(per_dev.get("collective_wire_bytes", 0.0)),
+        "hlo_flops": float(per_dev["flops"]),
+        "hlo_bytes": float(per_dev["bytes_fused"]),
+        "collective_bytes": float(per_dev["collective_operand_bytes"]),
+        "wire_bytes": float(per_dev["collective_wire_bytes"]),
         "model_flops": model_flops,
         "tokens_per_step": tokens_per_step,
     }
@@ -160,79 +159,102 @@ def train(model_cfg: ModelConfig, train_cfg: TrainConfig,
                      f"{start_step}")
             compiled = not jit
             while step < train_cfg.total_steps:
-                step_idx, np_batch = next(loader)
-                data_wait = loader.wait_time_s
-                batch = jax.device_put(
-                    np_batch, None if in_shardings is None
-                    else in_shardings[2])
-                if not compiled:
-                    # one-time (pre-execution, params still alive despite
-                    # donation): compile the step once and run that
-                    # artifact, whose HPM constants go to the agent —
-                    # including the real per-device collective operand /
-                    # wire bytes from the HLO walk
-                    train_step = train_step.lower(params, opt_state, batch,
-                                                  step_idx).compile()
-                    consts = compiled_step_constants(
-                        train_step, model_flops=model_flops,
-                        tokens_per_step=tokens_per_step)
-                    agent.set_step_constants(**consts)
-                    # static per-call work counters seeding the
-                    # train_step marker region's roofline operands
-                    step_counters = {
-                        k: v for k, v in
-                        (("flops", consts["hlo_flops"]),
-                         ("bytes", consts["hlo_bytes"]))
-                        if v > 0.0}
-                    compiled = True
+                # one step event per iteration; inside it the train.loop.*
+                # phases tile the iteration on this thread, so the trace
+                # can say what the host did while the device sat idle
+                with jax.profiler.StepTraceAnnotation("train",
+                                                      step_num=step):
+                    with TraceAnnotation("train.loop.data"):
+                        step_idx, np_batch = next(loader)
+                        data_wait = loader.wait_time_s
+                    with TraceAnnotation("train.loop.h2d"):
+                        batch = jax.device_put(
+                            np_batch, None if in_shardings is None
+                            else in_shardings[2])
+                    if not compiled:
+                        with TraceAnnotation("train.loop.compile"):
+                            # one-time (pre-execution, params still alive
+                            # despite donation): compile the step once and
+                            # run that artifact, whose HPM constants go to
+                            # the agent — including the real per-device
+                            # collective operand / wire bytes from the HLO
+                            # walk
+                            train_step = train_step.lower(
+                                params, opt_state, batch, step_idx).compile()
+                            consts = compiled_step_constants(
+                                train_step, model_flops=model_flops,
+                                tokens_per_step=tokens_per_step)
+                            agent.set_step_constants(**consts)
+                            # static per-call work counters seeding the
+                            # train_step marker region's roofline operands
+                            step_counters = {
+                                k: v for k, v in
+                                (("flops", consts["hlo_flops"]),
+                                 ("bytes", consts["hlo_bytes"]))
+                                if v > 0.0}
+                            compiled = True
 
-                if mk:
-                    mk.record("data_wait", data_wait)
-                t0 = time.monotonic()
-                with (mk.region("train_step", counters=step_counters or
-                                None) if mk else nullcontext()):
-                    # fwd + bwd + optimizer update are one fused jitted
-                    # step (donated buffers) — not separable into
-                    # sub-regions without splitting the compiled artifact
-                    params, opt_state, metrics = train_step(
-                        params, opt_state, batch, step_idx)
-                    loss = float(metrics["loss"])
-                step_time = time.monotonic() - t0
+                    # marker bookkeeping: the region's start and stop (and
+                    # the flush its stop may make) are monitoring, the step
+                    # inside it is its own phases
+                    with TraceAnnotation("train.loop.monitor"):
+                        if mk:
+                            mk.record("data_wait", data_wait)
+                        t0 = time.monotonic()
+                        with (mk.region("train_step",
+                                        counters=step_counters or None)
+                              if mk else nullcontext()):
+                            # fwd + bwd + optimizer update are one fused
+                            # jitted step (donated buffers) — not separable
+                            # into sub-regions without splitting the
+                            # compiled artifact
+                            with TraceAnnotation("train.loop.dispatch"):
+                                params, opt_state, metrics = train_step(
+                                    params, opt_state, batch, step_idx)
+                            with TraceAnnotation("train.loop.sync"):
+                                loss = float(metrics["loss"])
+                        step_time = time.monotonic() - t0
 
-                # LMS per-step emission
-                if train_cfg.monitor and \
-                        step_idx % train_cfg.monitor_interval == 0:
-                    agent.collect_step(step=step_idx, step_time_s=step_time,
-                                       extra_events={"data_wait_s":
-                                                     data_wait})
-                    um.metric("train",
-                              {"loss": loss,
-                               "grad_norm": float(metrics["grad_norm"]),
-                               "lr": float(metrics["lr"])})
-                if math.isnan(loss):
-                    um.event("run_state", f"NaN loss at step {step_idx}")
-                    halted["reason"] = "nan_loss"
+                    with TraceAnnotation("train.loop.monitor"):
+                        # LMS per-step emission
+                        if train_cfg.monitor and \
+                                step_idx % train_cfg.monitor_interval == 0:
+                            agent.collect_step(
+                                step=step_idx, step_time_s=step_time,
+                                extra_events={"data_wait_s": data_wait})
+                            um.metric("train",
+                                      {"loss": loss,
+                                       "grad_norm": float(
+                                           metrics["grad_norm"]),
+                                       "lr": float(metrics["lr"])})
+                        if math.isnan(loss):
+                            um.event("run_state",
+                                     f"NaN loss at step {step_idx}")
+                            halted["reason"] = "nan_loss"
 
-                last_loss = loss
-                steps_run += 1
-                step = step_idx + 1
+                        last_loss = loss
+                        steps_run += 1
+                        step = step_idx + 1
 
-                if step_callback:
-                    step_callback(step, metrics)
-                if ckpt and step % train_cfg.ckpt_interval == 0 and \
-                        not math.isnan(loss):
-                    with (mk.region("checkpoint") if mk
-                          else nullcontext()):
-                        ckpt.save(step, {"params": params,
-                                         "opt_state": opt_state},
-                                  {"arch": model_cfg.name, "step": step})
-                    um.event("run_state", f"checkpoint at {step}")
-                if fail_at_step is not None and step >= fail_at_step:
-                    um.event("run_state", f"injected failure at {step}")
-                    raise InjectedFailure(f"injected at step {step}")
-                if halted["reason"]:
-                    um.event("run_state", f"halt: {halted['reason']}")
-                    break
+                    if step_callback:
+                        with TraceAnnotation("train.loop.callback"):
+                            step_callback(step, metrics)
+                    if ckpt and step % train_cfg.ckpt_interval == 0 and \
+                            not math.isnan(loss):
+                        with TraceAnnotation("train.loop.ckpt"):
+                            with (mk.region("checkpoint") if mk
+                                  else nullcontext()):
+                                ckpt.save(step, {"params": params,
+                                                 "opt_state": opt_state},
+                                          {"arch": model_cfg.name,
+                                           "step": step})
+                            um.event("run_state", f"checkpoint at {step}")
+                    if fail_at_step is not None and step >= fail_at_step:
+                        um.event("run_state", f"injected failure at {step}")
+                        raise InjectedFailure(f"injected at step {step}")
+                    if halted["reason"]:
+                        um.event("run_state", f"halt: {halted['reason']}")
+                        break
             um.event("run_state", "finished")
             # flush inside the job bracket so marker points are enriched
             # with the live job's tags (jobid/username) by the router

@@ -40,12 +40,14 @@ class Optimizer:
 # --------------------------------------------------------------------------
 
 
+@jax.named_scope("optimizer")
 def global_norm(tree) -> jax.Array:
     leaves = [jnp.sum(jnp.square(x.astype(jnp.float32)))
               for x in jax.tree.leaves(tree)]
     return jnp.sqrt(sum(leaves))
 
 
+@jax.named_scope("optimizer")
 def clip_by_global_norm(tree, max_norm: float):
     norm = global_norm(tree)
     scale = jnp.minimum(1.0, max_norm / jnp.maximum(norm, 1e-9))
@@ -55,6 +57,7 @@ def clip_by_global_norm(tree, max_norm: float):
 
 def lr_schedule(cfg: TrainConfig):
     """Linear warmup -> cosine decay to 10% of peak."""
+    @jax.named_scope("optimizer")
     def lr(step):
         step = jnp.asarray(step, jnp.float32)
         warm = cfg.learning_rate * step / max(cfg.warmup_steps, 1)
@@ -79,6 +82,7 @@ def adamw(cfg: TrainConfig) -> Optimizer:
                 "v": jax.tree.map(zeros, params),
                 "count": jnp.zeros((), jnp.int32)}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params, lr):
         count = state["count"] + 1
         c1 = 1 - b1 ** count.astype(jnp.float32)
@@ -133,6 +137,7 @@ def adafactor(cfg: TrainConfig, momentum_dtype=jnp.bfloat16) -> Optimizer:
         return {"s": jax.tree.map(one, params),
                 "count": jnp.zeros((), jnp.int32)}
 
+    @jax.named_scope("optimizer")
     def update(grads, state, params, lr):
         count = state["count"] + 1
         beta2 = 1.0 - count.astype(jnp.float32) ** -0.8   # schedule

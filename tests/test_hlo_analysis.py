@@ -115,3 +115,71 @@ def test_collective_accounting_sharded():
         assert res["per_device"]["collective_operand_bytes"] == 0
     else:
         pytest.skip("multi-device path covered by test_multidevice")
+
+
+# What the TPU backend emits (trimmed from a v5e compile of the granite
+# train step): matmuls as convolutions inside fusions, a batched matmul as
+# a convolution over the batch dims with lhs_dilate = window size, and a
+# scan loop without ``known_trip_count``.
+_TPU_STYLE = """HloModule m
+
+%fused_mm (p0: bf16[2,2048,4096], p1: bf16[4096,12800,1]) -> bf16[2,2048,12800] {
+  %p0 = bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[4096,12800,1]{1,0,2:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution.30 = bf16[2,2048,12800]{2,1,0:T(8,128)(2,1)} convolution(%p0, %p1), window={size=1}, dim_labels=0bf_io0->0bf
+}
+
+%fused_bmm (q: bf16[2,8,4,2048,128], k: bf16[2,8,2048,128,1]) -> f32[2,8,4,2048,2048] {
+  %q = bf16[2,8,4,2048,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(0)
+  %k = bf16[2,8,2048,128,1]{2,3,4,1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %convolution-base-dilated.33 = f32[2,8,4,2048,2048]{3,4,2,1,0:T(8,128)} convolution(%q, %k), window={size=2x8x1 stride=1x7x1 lhs_dilate=2x8x1}, dim_labels=012bf_01oi2->012bf
+}
+
+%body (t: (s32[], f32[64,64])) -> (s32[], f32[64,64]) {
+  %t = (s32[]{:T(128)}, f32[64,64]{1,0:T(8,128)}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%t), index=0
+  %x = f32[64,64]{1,0:T(8,128)} get-tuple-element(%t), index=1
+  %one = s32[]{:T(128)} constant(1)
+  %i2 = s32[]{:T(128)} add(%i, %one)
+  %y = f32[64,64]{1,0:T(8,128)} convolution(%x, %x), dim_labels=bf_io->bf
+  ROOT %r = (s32[]{:T(128)}, f32[64,64]{1,0:T(8,128)}) tuple(%i2, %y)
+}
+
+%cond (t2: (s32[], f32[64,64])) -> pred[] {
+  %n = s32[]{:T(128)} constant(%N%)
+  %t2 = (s32[]{:T(128)}, f32[64,64]{1,0:T(8,128)}) parameter(0)
+  %i3 = s32[]{:T(128)} get-tuple-element(%t2), index=0
+  ROOT %lt = pred[]{:T(512)} compare(%i3, %n), direction=LT
+}
+
+ENTRY %main (a: bf16[2,2048,4096], w: bf16[4096,12800,1], q: bf16[2,8,4,2048,128], k: bf16[2,8,2048,128,1], x: f32[64,64]) -> f32[64,64] {
+  %a = bf16[2,2048,4096]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %w = bf16[4096,12800,1]{1,0,2:T(8,128)(2,1)} parameter(1)
+  %q = bf16[2,8,4,2048,128]{4,3,2,1,0:T(8,128)(2,1)} parameter(2)
+  %k = bf16[2,8,2048,128,1]{2,3,4,1,0:T(8,128)(2,1)} parameter(3)
+  %x0 = f32[64,64]{1,0:T(8,128)} parameter(4)
+  %mm = bf16[2,2048,12800]{2,1,0:T(8,128)(2,1)} fusion(%a, %w), kind=kOutput, calls=%fused_mm
+  %bmm = f32[2,8,4,2048,2048]{3,4,2,1,0:T(8,128)} fusion(%q, %k), kind=kOutput, calls=%fused_bmm
+  %zero = s32[]{:T(128)} constant(%START%)
+  %z = s32[]{:T(128)} copy(%zero)
+  %init = (s32[]{:T(128)}, f32[64,64]{1,0:T(8,128)}) tuple(%z, %x0)
+  %loop = (s32[]{:T(128)}, f32[64,64]{1,0:T(8,128)}) while(%init), condition=%cond, body=%body
+  ROOT %out = f32[64,64]{1,0:T(8,128)} get-tuple-element(%loop), index=1
+}
+"""
+
+
+@pytest.mark.parametrize("start,n", [(0, 5), (2, 5), (0, 40)])
+def test_tpu_convolutions_and_untagged_loop(start, n):
+    """Convolution FLOPs count only the taps that land on real input (a
+    batched matmul's dilated window has one per output), and a loop the
+    TPU backend leaves untagged runs ``N - start`` times, read off its
+    ``counter < N`` condition and its initial tuple."""
+    text = _TPU_STYLE.replace("%N%", str(n)).replace("%START%", str(start))
+    res = analyze_hlo(text)
+    assert res["trip_counts"] == {"loop": float(n - start)}
+    mlp = 2 * 2 * 2048 * 4096 * 12800           # [4096, 2048x2] @ [., 12800]
+    scores = 2 * (2 * 8 * 4) * 2048 * 2048 * 128  # 64 heads of S x S x 128
+    # a trip: the matmul, the counter's add and the condition's compare
+    loop = (n - start) * (2 * 64 ** 3 + 2)
+    assert res["per_device"]["flops"] == mlp + scores + loop

@@ -69,22 +69,67 @@ def test_make_mesh_axes_are_auto():
     assert set(make_mesh_for(1).axis_types) == {AxisType.Auto}
 
 
+_CACHE_CONFIG = ("jax_compilation_cache_dir",
+                 "jax_compilation_cache_include_metadata_in_key",
+                 "jax_persistent_cache_min_compile_time_secs",
+                 "jax_persistent_cache_min_entry_size_bytes")
+
+
 def test_compile_cache_placement(monkeypatch, tmp_path):
     """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
-    cache is the fixed <repo>/.jax_cache."""
+    cache is the fixed <repo>/.jax_cache.  Either way its keys include
+    the program's metadata."""
     from repro.launch.compile_cache import (REPO_CACHE_DIR,
                                             enable_compile_cache)
-    prev = jax.config.jax_compilation_cache_dir
+    prev = {k: getattr(jax.config, k) for k in _CACHE_CONFIG}
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert enable_compile_cache() == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == prev
+        assert jax.config.jax_compilation_cache_dir == \
+            prev["jax_compilation_cache_dir"]
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert enable_compile_cache() == str(REPO_CACHE_DIR)
         assert jax.config.jax_compilation_cache_dir == str(REPO_CACHE_DIR)
         assert REPO_CACHE_DIR.parent == Path(__file__).resolve().parents[1]
     finally:
-        jax.config.update("jax_compilation_cache_dir", prev)
+        for k, v in prev.items():
+            jax.config.update(k, v)
+
+
+def test_cached_executable_keeps_its_own_scopes(monkeypatch, tmp_path):
+    """Two builds of one HLO that differ only in a named scope, sharing a
+    cache directory: each compiled step carries its own scope, so a
+    profile of either attributes device time to that build's scopes."""
+    import jax.numpy as jnp
+    from jax._src import compilation_cache
+    from repro.launch.compile_cache import enable_compile_cache
+
+    def build(scope):
+        def step(x):
+            with jax.named_scope(scope):
+                return jnp.tanh(x @ x)
+        return jax.jit(step)
+
+    prev = {k: getattr(jax.config, k) for k in _CACHE_CONFIG}
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    try:
+        enable_compile_cache()
+        # JAX reads the variable at start-up, before this test set it
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        compilation_cache.reset_cache()
+        x = jnp.ones((8, 8))
+        first = build("parent_scope").lower(x).compile().as_text()
+        second = build("change_scope").lower(x).compile().as_text()
+        assert "parent_scope" in first
+        assert "change_scope" in second and "parent_scope" not in second
+        assert len(list(tmp_path.glob("jit_step-*"))) == 2
+    finally:
+        for k, v in prev.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()
 
 
 def test_dryrun_artifacts_schema():

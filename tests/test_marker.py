@@ -454,10 +454,7 @@ ENTRY %main (p: f32[1024,256]) -> f32[1024,256] {
 
 
 class _StubCompiled:
-    """Compiled-artifact shape: cost_analysis + as_text."""
-
-    def cost_analysis(self):
-        return {"flops": 1e9, "bytes accessed": 1e8}
+    """Compiled-artifact shape: as_text."""
 
     def as_text(self):
         return _SHARDED_HLO
@@ -467,8 +464,10 @@ def test_compiled_step_constants_threads_collective_bytes():
     from repro.train.loop import compiled_step_constants
     consts = compiled_step_constants(_StubCompiled(), model_flops=2e9,
                                      tokens_per_step=4096.0)
-    assert consts["hlo_flops"] == 1e9
-    assert consts["hlo_bytes"] == 1e8
+    # every count is the HLO walk's: an all-reduce does no flops and
+    # moves its operand in and its result out
+    assert consts["hlo_flops"] == 0.0
+    assert consts["hlo_bytes"] == pytest.approx(2 * 1024 * 256 * 4)
     # the seed hardcoded collective_bytes=0.0; the HLO walk sees the
     # all-reduce (1024*256 f32 operand = 1 MiB per device)
     assert consts["collective_bytes"] == pytest.approx(1024 * 256 * 4)
@@ -493,6 +492,46 @@ ENTRY %main (p: f32[8]) -> f32[8] {
                                      tokens_per_step=1.0)
     assert consts["collective_bytes"] == 0.0
     assert consts["wire_bytes"] == 0.0
+
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_compiled_step_constants_count_every_scanned_layer(d):
+    """XLA's cost_analysis counts a scanned layer body once; the constants
+    come from the trip-count-aware walk, so adding scanned layers adds
+    the same per-layer part to ``hlo_flops`` and to ``hlo_bytes``."""
+    import jax
+    import jax.numpy as jnp
+    from repro.train.loop import compiled_step_constants
+
+    def consts(layers):
+        def f(x, ws):
+            def layer(h, w):
+                return jnp.tanh(h @ w), None
+            return jax.lax.scan(layer, x, ws)[0].sum()
+        compiled = jax.jit(f).lower(
+            jax.ShapeDtypeStruct((8, d), jnp.float32),
+            jax.ShapeDtypeStruct((layers, d, d), jnp.float32)).compile()
+        return compiled_step_constants(compiled, model_flops=1.0,
+                                       tokens_per_step=1.0)
+
+    c = {n: consts(n) for n in (1, 2, 3, 4)}
+    flops = {n: c[n]["hlo_flops"] for n in c}
+    per_layer = flops[3] - flops[2]
+    # a layer is its 2*8*d*d matmul flops and a few elementwise ones
+    assert 2 * 8 * d * d <= per_layer <= 1.05 * 2 * 8 * d * d
+    # the part outside the layers is the same at every depth (a 1-trip
+    # loop is unrolled, which saves its counter's two flops)
+    assert flops[2] - flops[1] == pytest.approx(per_layer, rel=1e-3)
+
+    # bytes: each trip slices one (d, d) layer out of the stack, so the
+    # per-layer part is the same at every depth (from 2 layers, where the
+    # loop appears) and 2 -> 4 layers adds twice what 2 -> 3 does; charging
+    # each slice the whole (L, d, d) stack would grow it with L
+    hbm = {n: c[n]["hlo_bytes"] for n in c}
+    per_layer = hbm[3] - hbm[2]
+    assert hbm[4] - hbm[2] == pytest.approx(2 * per_layer, rel=1e-6)
+    # at least the layer's weights once, at most a few times over
+    assert 4 * d * d <= per_layer <= 4 * 4 * d * d
 
 
 def test_compiled_step_constants_walk_failure_propagates():

@@ -23,6 +23,7 @@ from repro.launch.hlo_analysis import analyze_hlo
 from repro.launch.steps import train_input_specs
 from repro.models.params import abstract_params
 from repro.models.transformer import model_specs
+from repro.train.loop import compiled_step_constants
 from repro.train.optim import opt_state_specs
 from repro.train.step import make_train_step
 
@@ -102,4 +103,38 @@ def test_granite_train_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES, used
-    assert compiled.cost_analysis()["flops"] > 0
+    # the HPM constants count both scanned layers; XLA's cost analysis
+    # counts the layer body once
+    consts = compiled_step_constants(compiled, model_flops=1.0,
+                                     tokens_per_step=1.0)
+    assert consts["hlo_flops"] > 1.4 * compiled.cost_analysis()["flops"]
+
+
+@pytest.mark.parametrize("d", [256, 512])
+def test_step_constants_count_every_scanned_layer(one_chip, d):
+    """The TPU backend lowers every matmul to a convolution and leaves the
+    scan loop without ``known_trip_count``: the HPM step constants still
+    add one layer's flops and bytes per scanned layer, so 2 -> 4 layers
+    adds twice what 2 -> 3 does."""
+    def consts(layers):
+        def f(x, ws):
+            def layer(h, w):
+                return jnp.tanh(h @ w), None
+            return jax.lax.scan(layer, x, ws)[0].sum()
+        compiled = jax.jit(f).lower(
+            _sds(one_chip, (8, d), jnp.float32),
+            _sds(one_chip, (layers, d, d), jnp.float32)).compile()
+        return compiled_step_constants(compiled, model_flops=1.0,
+                                       tokens_per_step=1.0)
+
+    c = {n: consts(n) for n in (1, 2, 3, 4)}
+    flops = {n: c[n]["hlo_flops"] for n in c}
+    per_layer = flops[3] - flops[2]
+    assert 2 * 8 * d * d <= per_layer <= 1.05 * 2 * 8 * d * d
+    assert flops[2] - flops[1] == pytest.approx(per_layer, rel=1e-3)
+    assert flops[4] - flops[2] == pytest.approx(2 * per_layer, rel=1e-6)
+    hbm = {n: c[n]["hlo_bytes"] for n in c}
+    per_layer = hbm[3] - hbm[2]
+    assert hbm[4] - hbm[2] == pytest.approx(2 * per_layer, rel=1e-6)
+    # the layer's (bf16) weights at least once, and not the whole stack
+    assert 2 * d * d <= per_layer <= 4 * 4 * d * d
