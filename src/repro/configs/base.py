@@ -328,7 +328,7 @@ class TrainConfig:
     num_microbatches: int = 1       # gradient accumulation
     remat_policy: str = "minimal"   # none | minimal | full
     grad_compression: str = "none"  # none | int8 | bf16  (DP all-reduce)
-    attn_impl: str = "masked"       # masked | recursive | flash (§Perf)
+    attn_impl: str = "auto"         # auto | masked | recursive | flash
     scan_unroll: int = 1            # layer-scan unroll factor
     grad_sync_dtype: str = "float32"  # float32 | bfloat16 DP reduction
     seq_parallel: bool = False      # Megatron-SP residual sharding (§Perf)

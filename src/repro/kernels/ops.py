@@ -66,9 +66,10 @@ def _costs(key, lower_fn, analytic_fn, interpret: bool) -> dict:
 
 
 def flash_attention_bshd(q, k, v, *, causal: bool = True, window: int = 0,
-                         bq: int = 128, bk: int = 128,
+                         bq: int = _fa.BLOCK_Q, bk: int = _fa.BLOCK_K,
                          interpret: bool = False):
-    """q: (B, S, H, D); k/v: (B, S, KV, D) -> (B, S, H, D)."""
+    """q: (B, S, H, D); k/v: (B, S, KV, D) -> (B, S, H, D).  Differentiable:
+    the kernel's backward runs under ``jax.grad``."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
@@ -84,7 +85,8 @@ def flash_attention_bshd(q, k, v, *, causal: bool = True, window: int = 0,
                                           window=window, bq=bq, bk=bk,
                                           interpret=interpret),
         lambda: _fa.cost_estimate(qt.shape, kt.shape[1], qt.dtype.itemsize,
-                                  causal=causal, window=window, bk=bk),
+                                  causal=causal, window=window, bq=bq,
+                                  bk=bk),
         interpret)
     with m.region("kernel:flash_attention", counters=costs):
         o = _fa.flash_attention(qt, kt, vt, causal=causal, window=window,

@@ -2,8 +2,10 @@
 
 Design notes
 ------------
-* **train** (seq <= ~8k): plain masked attention. The S^2 logits are
-  transient inside a rematted layer; at 4k this is the fastest XLA lowering.
+* **train** (seq <= ~8k): on one TPU, the Pallas flash kernels (forward
+  and backward; the S^2 scores stay in VMEM); elsewhere plain masked
+  attention, whose S^2 logits are transient inside a rematted layer.
+  ``select_attn_impl`` picks between them.
 * **prefill** (32k): k-chunked online-softmax attention (flash-style in pure
   XLA) so the S^2 logits never materialize at once.  No bwd needed.
 * **decode**: one query token against the KV cache, direct einsum; the cache
@@ -253,6 +255,24 @@ def recursive_causal_attention(q, k, v, *, levels=3, softcap=0.0,
 # --------------------------------------------------------------------------
 # GQA block (projections + rope + cache + attention)
 # --------------------------------------------------------------------------
+
+
+def select_attn_impl(attn_impl: str, cfg: ModelConfig, seq_len: int, *,
+                     mesh_devices: int, backend: str) -> str:
+    """The train path's attention implementation.  ``"auto"`` takes the
+    Pallas flash kernels where they serve: a TPU backend, attention inputs
+    on one device (a ``pallas_call`` is not partitioned by GSPMD), GQA
+    without a logit softcap, and a sequence and head dim the kernel's
+    blocks tile.  Everything else, the CPU backend included, runs
+    ``full_attention``.  Any other value is taken as given."""
+    if attn_impl != "auto":
+        return attn_impl
+    from repro.kernels.flash_attention import fits
+    if (backend == "tpu" and mesh_devices <= 1
+            and cfg.attention_type == "gqa" and not cfg.attn_logit_softcap
+            and fits(seq_len, cfg.head_dim)):
+        return "flash"
+    return "masked"
 
 
 def _ring_slots(pos, window):

@@ -24,7 +24,7 @@ from repro.configs.base import ModelConfig
 from repro.models import ssm as ssm_mod
 from repro.models.attention import (
     attn_specs, cross_attention, cross_kv, gqa_attention, mla_attention,
-    mla_specs)
+    mla_specs, select_attn_impl)
 from repro.models.layers import (
     apply_mlp, apply_norm, cross_entropy, embed_tokens, embedding_specs,
     lm_logits, mlp_specs, mrope_table, norm_specs, rope_table)
@@ -335,7 +335,7 @@ def _scan_layers(body, x, stacked_params, stacked_cache, *, remat="none",
 
 
 def forward(params, cfg: ModelConfig, *, tokens, mode="train", cache=None,
-            pos=None, pc=None, extras=None, attn_impl="masked",
+            pos=None, pc=None, extras=None, attn_impl="auto",
             remat="none", scan_unroll: int = 1, cache_update="onehot"):
     """Run the model.
 
@@ -348,6 +348,10 @@ def forward(params, cfg: ModelConfig, *, tokens, mode="train", cache=None,
     pc = pc or NullConstraints()
     extras = extras or {}
     b, s = tokens.shape
+    if mode == "train":
+        attn_impl = select_attn_impl(
+            attn_impl, cfg, s, backend=jax.default_backend(),
+            mesh_devices=pc.mesh.devices.size if pc.mesh is not None else 1)
     if pos is None:
         positions = jnp.arange(s)[None, :]
     else:
@@ -575,7 +579,7 @@ def _encdec_forward(params, x, cfg, *, mode, cache, pos, pc, extras,
 # ==========================================================================
 
 
-def loss_fn(params, cfg: ModelConfig, batch, *, pc=None, attn_impl="masked",
+def loss_fn(params, cfg: ModelConfig, batch, *, pc=None, attn_impl="auto",
             remat="none", scan_unroll: int = 1):
     """Next-token CE loss + aux.  batch: {"tokens", "labels", extras...}."""
     extras = {k: v for k, v in batch.items() if k not in ("tokens", "labels")}

@@ -37,7 +37,7 @@ def _grads_and_metrics(params, batch, model_cfg: ModelConfig,
     nm = train_cfg.num_microbatches
     vg = jax.value_and_grad(
         partial(loss_fn, cfg=model_cfg, pc=pc,
-                attn_impl=getattr(train_cfg, "attn_impl", "masked"),
+                attn_impl=getattr(train_cfg, "attn_impl", "auto"),
                 remat=train_cfg.remat_policy,
                 scan_unroll=getattr(train_cfg, "scan_unroll", 1)),
         has_aux=True)

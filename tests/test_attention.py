@@ -133,3 +133,48 @@ def test_moe_dispatch_invariants(rng):
     cap = capacity(cfg, b * s)
     assert cap % 8 == 0
     assert cap * cfg.moe.num_experts >= b * s * cfg.moe.top_k
+
+
+@pytest.mark.parametrize("impl,backend,devices,changes,seq,want", [
+    ("auto", "tpu", 1, {}, 2048, "flash"),
+    ("auto", "cpu", 1, {}, 2048, "masked"),             # CPU tests
+    ("auto", "tpu", 4, {}, 2048, "masked"),             # sharded inputs
+    ("auto", "tpu", 1, {"attn_logit_softcap": 30.0}, 2048, "masked"),
+    ("auto", "tpu", 1, {"attention_type": "mla"}, 2048, "masked"),
+    ("auto", "tpu", 1, {}, 2000, "masked"),             # off the tiling
+    ("auto", "tpu", 1, {"head_dim": 112}, 2048, "masked"),
+    ("auto", "tpu", 1, {"head_dim": 256}, 2048, "masked"),
+    ("auto", "tpu", 1, {"sliding_window": 4096}, 2048, "flash"),
+    ("masked", "tpu", 1, {}, 2048, "masked"),           # explicit choices
+    ("flash", "cpu", 1, {}, 2048, "flash"),
+])
+def test_select_attn_impl(impl, backend, devices, changes, seq, want):
+    """The train path's attention as a pure function of backend, mesh
+    size, softcap, attention type and shape."""
+    import dataclasses
+    from repro.models.attention import select_attn_impl
+    cfg = dataclasses.replace(get_config("granite-3-8b"), **changes)
+    assert select_attn_impl(impl, cfg, seq, mesh_devices=devices,
+                            backend=backend) == want
+
+
+@pytest.mark.parametrize("backend,kernel", [("cpu", False), ("tpu", True)])
+def test_default_train_step_attention(monkeypatch, backend, kernel):
+    """The default train step runs full_attention on the CPU backend and
+    the flash kernels on a TPU backend, for a shape they tile (traced only,
+    so no chip is needed)."""
+    import dataclasses
+    from repro.configs import TrainConfig
+    from repro.models.transformer import init_model_params
+    from repro.train.step import make_train_step
+
+    cfg = dataclasses.replace(get_config("granite-3-8b", smoke=True),
+                              head_dim=128)
+    params = init_model_params(cfg, seed=0)
+    step, opt = make_train_step(cfg, TrainConfig())
+    toks = jnp.zeros((1, 128), jnp.int32)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    jaxpr = str(jax.make_jaxpr(step)(params, opt.init(params),
+                                     {"tokens": toks, "labels": toks},
+                                     jnp.int32(0)))
+    assert ("pallas_call" in jaxpr) == kernel

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_attention as fa, ops, ref
 
 
 def _r(rng, shape, dtype=jnp.float32):
@@ -55,6 +55,58 @@ def test_flash_attention_block_shape_invariance(rng):
     b = ops.flash_attention_bshd(q, k, v, bq=32, bk=64, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5,
                                atol=1e-5)
+
+
+GRAD_CASES = [
+    # b, h, kv, s, d, causal, window, dtype, block
+    (1, 4, 1, 128, 32, True, 0, jnp.float32, 32),      # G=4, S = 4 x block
+    (1, 2, 2, 64, 32, True, 0, jnp.float32, 32),       # G=1
+    (1, 4, 1, 128, 32, True, 0, jnp.bfloat16, 32),
+    (1, 4, 2, 128, 32, True, 48, jnp.float32, 32),     # sliding window
+    (1, 2, 2, 64, 32, False, 0, jnp.float32, 32),      # bidirectional
+]
+
+
+@pytest.mark.parametrize("b,h,kv,s,d,causal,window,dtype,block", GRAD_CASES)
+def test_flash_attention_grad_allclose(rng, b, h, kv, s, d, causal, window,
+                                       dtype, block):
+    """jax.grad through the kernels' custom VJP == jax.grad of the dense
+    reference, dQ, dK and dV (dK/dV summed over each KV head's group)."""
+    import jax
+    q = _r(rng, (b, h, s, d), dtype)
+    k = _r(rng, (b, kv, s, d), dtype)
+    v = _r(rng, (b, kv, s, d), dtype)
+    w = _r(rng, (b, h, s, d), jnp.float32)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) * w)
+
+    got = jax.jit(jax.grad(loss(lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=causal, window=window, bq=block, bk=block,
+        interpret=True)), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.grad(loss(lambda q, k, v: ref.attention_ref(
+        q, k, v, causal=causal, window=window)), argnums=(0, 1, 2))(q, k, v)
+    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-4
+    for g, w_ in zip(got, want):
+        assert g.dtype == dtype
+        g, w_ = np.asarray(g, np.float32), np.asarray(w_, np.float32)
+        scale = max(1.0, float(np.abs(w_).max()))
+        np.testing.assert_allclose(g, w_, rtol=tol, atol=tol * scale)
+
+
+@pytest.mark.parametrize("s,block,causal,window,want", [
+    (2048, 512, True, 0, 10),           # 4 x 4 tiles, the diagonal's kept
+    (2048, 512, False, 0, 16),
+    (2048, 512, True, 512, 7),          # diagonal + one below
+    (2048, 256, True, 0, 36),
+])
+def test_flash_attention_live_blocks(s, block, causal, window, want):
+    """The tiles the kernels compute, which their declared FLOPs count."""
+    assert fa.live_blocks(s, block, block, causal=causal,
+                          window=window) == want
+    ce = fa.cost_estimate((2, 32, s, 128), 8, 2, causal=causal,
+                          window=window, bq=block, bk=block, kernel="dkv")
+    assert ce["flops"] == 4 * 2 * 128 * 2 * 32 * want * block * block
 
 
 # -- rmsnorm -------------------------------------------------------------------
@@ -109,11 +161,12 @@ def test_ssd_kernel_strong_decay_stable(rng):
 
 
 def test_flash_impl_matches_masked_at_model_level(rng):
-    """forward(attn_impl="flash") == forward(attn_impl="masked") for a
-    reduced dense config (kernel runs in interpret mode on CPU)."""
+    """forward and value_and_grad(loss_fn) with attn_impl="flash" match
+    attn_impl="masked" for a reduced dense config (kernel runs in interpret
+    mode on CPU)."""
     import jax
     from repro.configs import get_config
-    from repro.models.transformer import forward, init_model_params
+    from repro.models.transformer import forward, init_model_params, loss_fn
 
     cfg = get_config("granite-3-8b", smoke=True)
     params = init_model_params(cfg, seed=0)
@@ -125,3 +178,21 @@ def test_flash_impl_matches_masked_at_model_level(rng):
     np.testing.assert_allclose(
         np.asarray(fl_logits, np.float32), np.asarray(ref_logits, np.float32),
         rtol=5e-2, atol=5e-2)   # bf16 activations
+
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+
+    def value_and_grad(impl):
+        return jax.jit(jax.value_and_grad(
+            lambda p: loss_fn(p, cfg, batch, attn_impl=impl,
+                              remat="minimal")[0]))(params)
+
+    (l_ref, g_ref), (l_fl, g_fl) = value_and_grad("masked"), \
+        value_and_grad("flash")
+    np.testing.assert_allclose(float(l_fl), float(l_ref), rtol=1e-3)
+    flat_ref, flat_fl = jax.tree.leaves(g_ref), jax.tree.leaves(g_fl)
+    assert len(flat_ref) == len(flat_fl)
+    for a, b in zip(flat_fl, flat_ref):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        # bf16 activations: each leaf within 5 % of its own scale
+        np.testing.assert_allclose(a, b, rtol=0,
+                                   atol=5e-2 * float(np.abs(b).max()) + 1e-6)

@@ -67,6 +67,28 @@ def test_flash_attention_compiles(one_chip, dtype):
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_flash_attention_grad_compiles(one_chip, dtype):
+    """The backward kernels at granite-3-8b widths (B=2, S=2048) within the
+    scoped VMEM; the walk reads each kernel's declared causal FLOPs, so
+    forward + dQ + dK/dV is what ``cost_estimate`` counts (plus XLA's own
+    ``rowsum(dO * O)``)."""
+    q = _sds(one_chip, (2, 32, 2048, 128), dtype)
+    kv = _sds(one_chip, (2, 8, 2048, 128), dtype)
+
+    def loss(q, k, v):
+        return fa.flash_attention(q, k, v).astype(jnp.float32).sum()
+    text = _kernel_text(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv))
+    for name in ("flash_attention_dq", "flash_attention_dkv"):
+        assert f"/{name}/pallas_call" in text
+    declared = sum(fa.cost_estimate(q.shape, 8, jnp.dtype(dtype).itemsize,
+                                    kernel=k)["flops"]
+                   for k in ("fwd", "dq", "dkv"))
+    flops = analyze_hlo(text)["per_device"]["flops"]
+    assert declared <= flops <= 1.01 * declared
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 def test_rmsnorm_compiles(one_chip, dtype):
     """d_model 4096; fp32 rows once overflowed the scoped VMEM."""
     _kernel_text(rms.rmsnorm.lower(_sds(one_chip, (4096, 4096), dtype),
@@ -82,10 +104,11 @@ def test_ssd_scan_compiles(one_chip):
     _kernel_text(ssd.ssd_scan.lower(x, a, bc, bc, chunk=128))
 
 
-def test_granite_train_step_fits_one_chip(one_chip):
-    """The smoke's monitored train step: granite-3-8b at published widths,
-    2 layers, B=2, S=2048, fp32 params + AdamW — arguments plus
-    temporaries within one v5e's 16 GiB."""
+@pytest.fixture(scope="module")
+def granite_step(one_chip):
+    """The smoke's monitored train step compiled for one v5e, by the backend
+    its attention selection sees: ``"cpu"`` runs ``full_attention``,
+    ``"tpu"`` the flash kernels (``models.attention.select_attn_impl``)."""
     cfg = dataclasses.replace(get_config("granite-3-8b"), num_layers=2)
     tcfg = TrainConfig()
     shape = ShapeConfig("smoke", seq_len=2048, global_batch=2, kind="train")
@@ -98,8 +121,24 @@ def test_granite_train_step_fits_one_chip(one_chip):
     args = (sds(pspecs), sds(opt_state_specs(pspecs, tcfg)),
             sds(train_input_specs(cfg, shape)),
             _sds(one_chip, (), jnp.int32))
-    step, _ = make_train_step(cfg, tcfg)
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(*args).compile()
+    cache = {}
+
+    def compiled(backend):
+        if backend not in cache:
+            with pytest.MonkeyPatch.context() as mp:
+                mp.setattr(jax, "default_backend", lambda: backend)
+                step, _ = make_train_step(cfg, tcfg)
+                cache[backend] = jax.jit(step, donate_argnums=(0, 1)).lower(
+                    *args).compile()
+        return cache[backend]
+    return compiled
+
+
+def test_granite_train_step_fits_one_chip(granite_step):
+    """The smoke's monitored train step: granite-3-8b at published widths,
+    2 layers, B=2, S=2048, fp32 params + AdamW — arguments plus
+    temporaries within one v5e's 16 GiB."""
+    compiled = granite_step("cpu")
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES, used
@@ -108,6 +147,33 @@ def test_granite_train_step_fits_one_chip(one_chip):
     consts = compiled_step_constants(compiled, model_flops=1.0,
                                      tokens_per_step=1.0)
     assert consts["hlo_flops"] > 1.4 * compiled.cost_analysis()["flops"]
+
+
+def test_granite_train_step_flash_kernels(granite_step):
+    """On the chip the default step takes the flash kernels, under the
+    ``attention`` scope the step's device-time shares read; it holds less
+    than the masked step, and its HPM FLOPs drop by no more than the
+    masked half of the attention products the masked step computes."""
+    import re
+    masked, flash = granite_step("cpu"), granite_step("tpu")
+    text = flash.as_text()
+    names = re.findall(r'custom_call_target="tpu_custom_call".*?'
+                       r'op_name="([^"]*)"', text)
+    assert {n.split("/")[-2] for n in names} == {
+        "flash_attention", "flash_attention_dq", "flash_attention_dkv"}
+    assert all("/attention/" in n for n in names), names
+
+    def temp(c):
+        return c.memory_analysis().temp_size_in_bytes
+    assert temp(flash) < temp(masked) - 2 ** 30
+
+    def flops(c):
+        return compiled_step_constants(c, model_flops=1.0,
+                                       tokens_per_step=1.0)["hlo_flops"]
+    b, h, s, d, layers = 2, 32, 2048, 128, 2
+    # QK^T, PV forward and their four backward products, half masked
+    masked_half = 3 * 2 * b * h * s * s * d * layers
+    assert 0 < flops(masked) - flops(flash) <= masked_half
 
 
 @pytest.mark.parametrize("d", [256, 512])
