@@ -10,12 +10,23 @@ batch per padded prompt length of the deck, which compiles every prefill
 and the decode program the window uses.
 
 The window opens after warm-up and closes when the first batch ending
-``--seconds`` later is answered.  Then a sample of the window's requests,
-drawn from the seed and holding the longest, is run through the plain
-reference (``reference/moe_serve.py``) over the prompt as the engine laid
-it out (left-padded with id 0 to the batch's length) and the served
-tokens: ``served_logit_gap`` is the widest gap by which a served token's
-reference logit lies below the reference's best at its position.
+``--seconds`` later is answered.  Every call of the engine's decode step in
+the window is stamped on the host clock: the gaps between a batch's
+consecutive calls are the inter-token latency its clients see.
+
+Then the check.  Whole batches of the window, drawn from the seed (the one
+holding the longest request first) until they hold ``sample_requests``
+requests, are run again through the engine's own jitted prefill and
+decode, laid out as ``run_batch`` lays them out, with the served tokens
+forced as the decode inputs (``replay``): the same compiled programs at
+the same shapes, so ``replay_tokens_mismatch`` counts the served tokens
+that the replay's argmax does not give again (an exact count).  The
+replay's logits at the served positions, over the real vocabulary, are
+compared with the plain reference's (``reference/moe_serve.py``, float32
+at ``highest`` precision) over the prompt as the engine laid it out
+(left-padded with id 0 to the batch's length) and the served tokens:
+``served_logit_rel_err`` is the mean over those positions of
+``|l_prog - l_ref| / |l_ref|`` (L2 norms).
 
 The weights are made here, on the device, in one jitted call from the
 seed, in the program's layout and in the type they are served in.
@@ -28,10 +39,11 @@ import gc
 import math
 import random
 import time
+from typing import Optional
 
 import numpy as np
 
-from benchmarks.lms_bench import bench, trace_reduce
+from benchmarks.lms_bench import bench, servetrace, trace_reduce
 from benchmarks.lms_bench.generators.train import _start_trace
 from benchmarks.lms_bench.hostspans import Spans
 from benchmarks.lms_bench.reference import moe_serve
@@ -130,6 +142,15 @@ class Batch:
     requests: list              # finished repro Requests
 
 
+@dataclasses.dataclass
+class Window:
+    t0: float
+    t_end: float
+    batches: list               # [Batch]
+    ann: object                 # the traced window's annotation, or None
+    trace_from: Optional[int]   # index of the first traced batch
+
+
 def batch_requests(traffic: dict, k: int, rng, vocab: int) -> list:
     """(prompt ids, new tokens) of the deck's k-th batch; ``rng`` is a
     numpy Generator."""
@@ -158,23 +179,48 @@ def _warmup(engine, traffic, rng, vocab):
         _serve(engine, reqs)
 
 
+class DecodeClock:
+    """Host-clock stamps of the decode step's calls, one list per batch,
+    taken while ``active``."""
+
+    def __init__(self):
+        self.active = False
+        self.stamps = []
+
+    def wrap(self, fn):
+        def clocked(*args, **kwargs):
+            if self.active:
+                self.stamps[-1].append(time.monotonic())
+            return fn(*args, **kwargs)
+        return clocked
+
+    def new_batch(self):
+        self.stamps.append([])
+
+    def gaps(self) -> list:
+        return [b - a for st in self.stamps for a, b in zip(st, st[1:])]
+
+
 def serve_window(engine, traffic, seed, seconds, trace_dir=None,
-                 n_batches=None):
+                 n_batches=None, clock=None) -> Window:
     """Warm-up, then batches until one ends ``seconds`` after the window
-    opened (or ``n_batches`` of them).  Returns (t0, t_end, batches,
-    trace annotation)."""
+    opened (or ``n_batches`` of them)."""
     vocab = engine.cfg.vocab_size
     rng = np.random.default_rng(bench.seed31(seed))
     _warmup(engine, traffic, np.random.default_rng(bench.seed31(seed) + 1),
             vocab)
+    if clock is not None:
+        clock.active = True
     t0 = time.monotonic()
-    batches, ann, k = [], None, 0
+    batches, ann, trace_from, k = [], None, None, 0
     while True:
         reqs = batch_requests(traffic, k, rng, vocab)
         start = time.monotonic()
         if trace_dir is not None and ann is None and \
                 start >= t0 + seconds - traffic["trace_s"]:
-            ann = _start_trace(trace_dir)
+            ann, trace_from = _start_trace(trace_dir), k
+        if clock is not None:
+            clock.new_batch()
         done = _serve(engine, reqs)
         end = time.monotonic()
         plen = max(len(r.prompt) for r in done)
@@ -183,7 +229,9 @@ def serve_window(engine, traffic, seed, seconds, trace_dir=None,
         if (n_batches is None and end - t0 >= seconds) or k == n_batches:
             if ann is not None:
                 ann.__exit__(None, None, None)
-            return t0, end, batches, ann
+            if clock is not None:
+                clock.active = False
+            return Window(t0, end, batches, ann, trace_from)
 
 
 # --------------------------------------------------------------------------
@@ -191,15 +239,68 @@ def serve_window(engine, traffic, seed, seconds, trace_dir=None,
 # --------------------------------------------------------------------------
 
 
-def sample(batches, n: int, seed: int) -> list:
-    """(request, batch plen) pairs: the longest request and ``n - 1``
-    others drawn from the seed."""
-    allr = [(r, b.plen) for b in batches for r in b.requests]
-    longest = max(allr, key=lambda rp: (rp[1] + len(rp[0].output),
-                                        len(rp[0].output)))
-    rest = [rp for rp in allr if rp[0] is not longest[0]]
-    rng = random.Random(bench.seed31(seed) + 2)
-    return [longest] + rng.sample(rest, min(n - 1, len(rest)))
+def sample_batches(batches, n: int, seed: int) -> list:
+    """Indices of whole batches holding at least ``n`` requests: a batch
+    holding a longest request (prompt and answer; the seed breaks ties),
+    then others drawn from the seed."""
+    order = list(range(len(batches)))
+    random.Random(bench.seed31(seed) + 2).shuffle(order)
+    longest = max(order, key=lambda k: max(
+        (batches[k].plen + len(r.output), len(r.output))
+        for r in batches[k].requests))
+    picked, rows = [], 0
+    for k in [longest] + [k for k in order if k != longest]:
+        if rows >= n:
+            break
+        picked.append(k)
+        rows += len(batches[k].requests)
+    return picked
+
+
+def replay(engine, batch: Batch):
+    """The batch run again through the engine's own jitted prefill and
+    decode, laid out as ``run_batch`` lays it out (prompts left-padded to
+    the batch's length, a fresh ``init_cache`` of ``max_len``, the same
+    positions), each row's served tokens forced as its decode inputs (a
+    row past its answer gets the step's own argmax, as in ``run_batch``).
+    Returns (served tokens that the replay's argmax does not give,
+    [each row's logits at its served positions, real vocabulary, float32])."""
+    import jax.numpy as jnp
+    import repro.serve.engine as engine_mod
+    reqs, plen = batch.requests, batch.plen
+    vocab = engine.cfg.vocab_size
+    toks = np.zeros((len(reqs), plen), np.int32)
+    for i, r in enumerate(reqs):
+        toks[i, plen - len(r.prompt):] = r.prompt
+    cache = engine_mod.init_cache(engine.cfg, len(reqs), engine.max_len)
+    logits, cache = engine.prefill(engine.params, jnp.asarray(toks), cache)
+    steps = max(r.max_new_tokens for r in reqs)
+    rows, mismatch = [[] for _ in reqs], 0
+    for s in range(steps):
+        nxt = np.array(jnp.argmax(logits, axis=-1))
+        host = np.asarray(logits)[:, :vocab].astype(np.float32)
+        for i, r in enumerate(reqs):
+            if s < len(r.output):
+                rows[i].append(host[i])
+                mismatch += int(nxt[i] != r.output[s])
+                nxt[i] = r.output[s]
+        if s + 1 < steps:
+            logits, cache = engine.decode(engine.params, cache,
+                                          jnp.asarray(nxt)[:, None],
+                                          jnp.int32(plen + s))
+    return mismatch, [np.stack(x) for x in rows]
+
+
+def replay_sample(engine, batches, n: int, seed: int):
+    """``replay`` of the sampled batches: (mismatches, [(request, batch
+    plen, program logits)])."""
+    mismatch, picked = 0, []
+    for k in sample_batches(batches, n, seed):
+        m, rows = replay(engine, batches[k])
+        mismatch += m
+        picked += [(r, batches[k].plen, lg)
+                   for r, lg in zip(batches[k].requests, rows)]
+    return mismatch, picked
 
 
 def laid_out(req, plen: int, max_len: int):
@@ -214,28 +315,56 @@ def laid_out(req, plen: int, max_len: int):
     return seq, positions
 
 
-def gaps(params, conf, picked, max_len, control=None) -> dict:
-    """The reference's gap of every served token (widest and mean over the
-    sample); with ``control``, the same for the token the control puts
-    first at each of those positions."""
+# A routing decision whose margin in the reference (second expert's
+# probability less the third's) is under this can go either way in a bf16
+# program: its router sees activations about 1 % off the float32 ones,
+# which moves a probability of ~0.2 by ~2e-3.
+ROUTE_EPS = 3e-3
+
+
+def readings(params, conf, picked, max_len, control=None) -> dict:
+    """The program's logits (``picked``: [(request, batch plen, logits at
+    its served positions)]) against the reference's at the same positions:
+    ``served_logit_rel_err``, the mean relative L2 error over the served
+    positions whose routing is decided (``ROUTE_EPS``): a position whose
+    own margin in the reference is under it is left out, and so is a row
+    whose left padding's is, since one pad token's choice of experts is
+    repeated at every pad position that all of the row's tokens attend.
+    Logged, not compared: the same mean over every position
+    (``served_logit_rel_err_all``) and ``served_logit_gap``, the widest gap
+    by which a served token's reference logit lies below the reference's
+    best.  With ``control`` (a narrow float type), the reference in that
+    precision read the same way: ``control_logit_rel_err``."""
     import jax.numpy as jnp
     key = moe_serve.cfg_key(conf)
-    served, ctl = [], []
-    for req, plen in picked:
+    rel, every, gap, ctl, rows_out = [], [], [], [], 0
+    for req, plen, prog in picked:
         seq, pos = laid_out(req, plen, max_len)
-        ref = moe_serve.logits(params, jnp.asarray(seq), key)
-        served.append(moe_serve.token_gaps(
-            ref, pos, np.asarray(req.output, np.int32)))
+        seq, at = jnp.asarray(seq), jnp.asarray(pos)
+        ref, margin = moe_serve.logits(params, seq, key)
+        margin = np.asarray(margin)
+        pad = plen - len(req.prompt)
+        keep = margin[pos] >= ROUTE_EPS
+        if pad and margin[:pad].min() < ROUTE_EPS:
+            keep[:], rows_out = False, rows_out + 1
+        rows = np.asarray(ref[at])
+        err = moe_serve.rel_errors(prog, rows)
+        every.append(err)
+        rel.append(err[keep])
+        gap.append(moe_serve.token_gaps(ref, pos,
+                                        np.asarray(req.output, np.int32)))
         if control is not None:
-            low = moe_serve.logits(params, jnp.asarray(seq), key, control)
-            first = np.asarray(jnp.argmax(low[jnp.asarray(pos)], axis=-1))
-            ctl.append(moe_serve.token_gaps(ref, pos, first))
-    out = {}
-    for name, g in (("served", served), ("control", ctl)):
-        if g:
-            g = np.concatenate(g)
-            out[f"{name}_logit_gap"] = float(g.max())
-            out[f"{name}_logit_gap_mean"] = float(g.mean())
+            low = moe_serve.logits(params, seq, key, control)[0]
+            ctl.append(moe_serve.rel_errors(np.asarray(low[at]), rows)[keep])
+    rel = np.concatenate(rel)
+    bench.log(f"check: {len(rel)} of {sum(len(e) for e in every)} served "
+              f"positions compared; {rows_out} rows left out for their "
+              "padding's routing")
+    out = {"served_logit_rel_err": float(rel.mean()),
+           "served_logit_rel_err_all": float(np.concatenate(every).mean()),
+           "served_logit_gap": float(np.concatenate(gap).max())}
+    if ctl:
+        out["control_logit_rel_err"] = float(np.concatenate(ctl).mean())
     return out
 
 
@@ -250,14 +379,18 @@ def _bad_requests(batches, vocab) -> int:
 # --------------------------------------------------------------------------
 
 
-def _engine(cell, seed, stack):
+def _engine(cell, params, stack):
     from repro.serve.engine import ServingEngine
-    cfg = model_config(cell.config)
-    params = make_weights(cfg, cell.config, bench.seed31(seed))
     um = stack.usermetric(host=JOB_HOST)
-    engine = ServingEngine(cfg, params, max_batch=cell.traffic["max_batch"],
+    engine = ServingEngine(model_config(cell.config), params,
+                           max_batch=cell.traffic["max_batch"],
                            max_len=cell.traffic["max_len"], usermetric=um)
     return engine, um
+
+
+def _weights(cell, seed):
+    return make_weights(model_config(cell.config), cell.config,
+                        bench.seed31(seed))
 
 
 def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
@@ -273,10 +406,12 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
         shutil.rmtree(trace_dir)
     stack = MonitoringStack.inprocess(out_dir=str(out / "lms"))
     spans = Spans() if trace else None
+    clock = DecodeClock()
     try:
         with stack.job(f"lms-bench-{cell.name}", user="bench",
                        hosts=[JOB_HOST]):
-            engine, um = _engine(cell, seed, stack)
+            engine, um = _engine(cell, _weights(cell, seed), stack)
+            engine.decode = clock.wrap(engine.decode)
             if spans is not None:
                 # name the device's idle gaps by the engine's host calls
                 import repro.serve.engine as engine_mod
@@ -284,11 +419,10 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
                 spans.install_attr(engine, "decode", "serve:decode")
                 spans.install_attr(engine_mod, "init_cache",
                                    "serve:init_cache")
-            t0, t_end, batches, ann = serve_window(
-                engine, traffic, seed, seconds,
-                trace_dir if trace else None)
+            w = serve_window(engine, traffic, seed, seconds,
+                             trace_dir if trace else None, clock=clock)
             um.flush()
-        if ann is not None:
+        if w.ann is not None:
             jax.profiler.stop_trace()
         mem = devices[0].memory_stats() or {}
         peak = int(mem.get("peak_bytes_in_use", 0))
@@ -296,59 +430,113 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
         if spans is not None:
             spans.uninstall()
         stack.close()
+    batches = w.batches
+    # each batch: its time, then to its first decode call (init_cache,
+    # prefill, the first token's sync) and its longest gap between calls
     bench.log("batches " + " ".join(
-        f"{b.plen}:{b.end - b.start:.3f}s" for b in batches))
+        f"{b.plen}:{b.end - b.start:.3f}s({(st or [b.end])[0] - b.start:.3f}"
+        f"/{max((y - x for x, y in zip(st, st[1:])), default=0.0):.4f})"
+        for b, st in zip(batches, clock.stamps)))
 
-    vocab = cell.config["vocab_size"]
     reqs = [r for b in batches for r in b.requests]
     delivered = sum(len(r.output) for r in reqs)
-    window_s = t_end - t0
+    window_s = w.t_end - w.t0
     e2e = {"serve_tokens_per_s": delivered / window_s,
-           "setup_s": t0 - t_process}
-    bad = _bad_requests(batches, vocab)
-    picked = sample(batches, traffic["sample_requests"], seed)
+           "setup_s": w.t0 - t_process}
+    bad = _bad_requests(batches, cell.config["vocab_size"])
+    t = time.monotonic()
+    mismatch, picked = replay_sample(engine, batches,
+                                     traffic["sample_requests"], seed)
+    t_replay = time.monotonic() - t
     params = engine.params
     engine = None
     gc.collect()
-    g = gaps(params, cell.config, picked, traffic["max_len"])
+    g = readings(params, cell.config, picked, traffic["max_len"])
+    bench.log(f"check: {len(picked)} requests, "
+              f"{sum(len(lg) for _, _, lg in picked)} served positions; "
+              f"replay {t_replay:.1f} s, reference "
+              f"{time.monotonic() - t - t_replay:.1f} s")
+    g["replay_tokens_mismatch"] = float(mismatch)
     g["requests_wrong"] = float(bad)
-    bench.log(f"widest served gap {g['served_logit_gap']!r} (not compared)")
-    checks = [bench.Check(n, g[n], cell.limits[n])
-              for n in cell.limits]
+    bench.log(f"not compared: mean over every position "
+              f"{g['served_logit_rel_err_all']!r}, widest served gap "
+              f"{g['served_logit_gap']!r}")
+    checks = [bench.Check(n, g[n], cell.limits[n]) for n in cell.limits]
     ctx = {"serve_batches": batches, "window_s": window_s,
            "config": cell.config, "chips": len(devices),
            "device_kind": devices[0].device_kind,
-           "max_batch": traffic["max_batch"]}
+           "max_batch": traffic["max_batch"],
+           "decode_gaps_s": clock.gaps(), "trace_from": w.trace_from}
     outcome = bench.Outcome(e2e, len(reqs), bad, checks, peak, ctx)
     if trace:
         outcome.trace = trace_reduce.reduce_dir(
             str(trace_dir), excerpt_path=out / "trace_excerpt.json")
         ctx["trace"] = outcome.trace
+        ctx["serve_trace"] = servetrace.load(
+            trace_reduce.find_xplane(str(trace_dir)))
     return outcome
+
+
+# --------------------------------------------------------------------------
+# calibration: the program, the control and planted faults
+# --------------------------------------------------------------------------
+
+
+def _decode_pos_off(engine):
+    """The decode step given ``pos - 1``: each token's key and value
+    overwrite the one before, and its rotary position is one off."""
+    decode = engine.decode
+    engine.decode = lambda params, cache, tokens, pos: decode(
+        params, cache, tokens, pos - 1)
+
+
+def _expert_dropped(engine):
+    """Expert 0 of every layer gives nothing (its down projection zeroed
+    in the program's weights only)."""
+    p = engine.params
+    moe = dict(p["moe_layers"]["moe"])
+    moe["w_down"] = moe["w_down"].at[:, 0].set(0)
+    engine.params = dict(p, moe_layers=dict(p["moe_layers"], moe=moe))
+
+
+FAULTS = {"decode_pos_off": _decode_pos_off,
+          "expert_dropped": _expert_dropped}
+
+
+def check_pass(cell: bench.Cell, params, seed: int, stack, plant=None,
+               control=None) -> dict:
+    """One pass of the batch deck at the cell's load (with ``plant``
+    applied to the engine), then the check's readings; with ``control``,
+    the control's as well."""
+    traffic = cell.traffic
+    with stack.job(f"lms-bench-cal-{seed}", user="bench", hosts=[JOB_HOST]):
+        engine, _ = _engine(cell, params, stack)
+        if plant is not None:
+            plant(engine)
+        batches = serve_window(engine, traffic, seed, 0.0,
+                               n_batches=len(traffic["batches"])).batches
+    mismatch, picked = replay_sample(engine, batches,
+                                     traffic["sample_requests"], seed)
+    engine = None
+    gc.collect()
+    g = readings(params, cell.config, picked, traffic["max_len"], control)
+    g["replay_tokens_mismatch"] = mismatch
+    g["requests_wrong"] = _bad_requests(batches, cell.config["vocab_size"])
+    return g
 
 
 def calibrate_seed(cell: bench.Cell, seed: int, stack,
                    control: bool = True) -> dict:
-    """The program's and the control's readings on one seed: one pass of
-    the batch deck at the cell's load, then the sample's comparison."""
+    """The program's readings on one seed; with ``control``, the float8
+    control's and each planted fault's too."""
     import jax.numpy as jnp
-    traffic = cell.traffic
-    with stack.job(f"lms-bench-cal-{seed}", user="bench", hosts=[JOB_HOST]):
-        engine, um = _engine(cell, seed, stack)
-        _, _, batches, _ = serve_window(engine, traffic, seed, 0.0,
-                                        n_batches=len(traffic["batches"]))
-    params = engine.params
-    engine = None
-    picked = sample(batches, traffic["sample_requests"], seed)
-    g = gaps(params, cell.config, picked, traffic["max_len"],
-             control=jnp.float8_e4m3fn if control else None)
-    out = {"seed": seed,
-           "program": {k: g[k] for k in ("served_logit_gap",
-                                         "served_logit_gap_mean")},
-           "requests_wrong": _bad_requests(batches,
-                                           cell.config["vocab_size"])}
+    params = _weights(cell, seed)
+    prog = check_pass(cell, params, seed, stack,
+                      control=jnp.float8_e4m3fn if control else None)
+    out = {"seed": seed, "program": prog}
     if control:
         out["control_fp8"] = {
-            "served_logit_gap": g["control_logit_gap"],
-            "served_logit_gap_mean": g["control_logit_gap_mean"]}
+            "served_logit_rel_err": prog.pop("control_logit_rel_err")}
+        for name, plant in FAULTS.items():
+            out[name] = check_pass(cell, params, seed, stack, plant=plant)
     return out

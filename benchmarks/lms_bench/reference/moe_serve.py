@@ -12,6 +12,10 @@ gate (zero where the token is not routed to it), which is the same sum.
 
 ``mm_dtype`` gives the control: every matmul operand per-tensor scaled
 through that narrow float type.
+
+Beside the logits, the pass gives each position's routing margin: the
+least, over the layers, of the router's second-largest probability less
+its third, i.e. how close the position's choice of experts came to a tie.
 """
 
 from __future__ import annotations
@@ -21,6 +25,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 NEG = -1e9
 
@@ -75,6 +80,8 @@ def _moe(h, m, cfg, mm_dtype):
     e, k = cfg["num_local_experts"], cfg["num_experts_per_tok"]
     probs = jax.nn.softmax(_mm("sd,de->se", h, m["router"], mm_dtype),
                            axis=-1)
+    ranked = jax.lax.top_k(probs, k + 1)[0]
+    margin = ranked[:, k - 1] - ranked[:, k]
     top, idx = jax.lax.top_k(probs, k)
     top = top / jnp.sum(top, axis=-1, keepdims=True)
     gate = jnp.sum(jax.nn.one_hot(idx, e) * top[..., None], axis=1)  # (S,E)
@@ -86,25 +93,28 @@ def _moe(h, m, cfg, mm_dtype):
         return acc + gate[:, i, None] * y, None
 
     out, _ = jax.lax.scan(expert, jnp.zeros_like(h), jnp.arange(e))
-    return out
+    return out, margin
 
 
 @partial(jax.jit, static_argnums=(2, 3))
 def logits(params, tokens, cfg_items: tuple, mm_dtype=None):
-    """(S,) token ids -> (S, vocab_size) float32 logits."""
+    """(S,) token ids -> ((S, vocab_size) float32 logits, (S,) routing
+    margins)."""
     cfg = dict(cfg_items)
     eps = cfg["rms_norm_eps"]
     x = params["embed"]["embedding"][tokens].astype(jnp.float32)
     layers = params["moe_layers"]
+    margin = jnp.full(tokens.shape, jnp.inf)
     for i in range(cfg["num_hidden_layers"]):
         lp = jax.tree.map(lambda w: w[i], layers)
         x = x + _attention(_rms(x, lp["ln1"]["scale"], eps), lp["attn"], cfg,
                            mm_dtype)
-        x = x + _moe(_rms(x, lp["ln2"]["scale"], eps), lp["moe"], cfg,
-                     mm_dtype)
+        y, m = _moe(_rms(x, lp["ln2"]["scale"], eps), lp["moe"], cfg,
+                    mm_dtype)
+        x, margin = x + y, jnp.minimum(margin, m)
     x = _rms(x, params["final_norm"]["scale"], eps)
     out = _mm("sd,dv->sv", x, params["embed"]["lm_head"], mm_dtype)
-    return out[:, :cfg["vocab_size"]]
+    return out[:, :cfg["vocab_size"]], margin
 
 
 def cfg_key(conf: dict) -> tuple:
@@ -123,3 +133,10 @@ def token_gaps(ref_logits, positions, tokens):
     best = jnp.max(rows, axis=-1)
     got = jnp.take_along_axis(rows, jnp.asarray(tokens)[:, None], axis=-1)
     return jax.device_get(best - got[:, 0])
+
+
+def rel_errors(logits, ref_rows):
+    """For each position (row), ``|logits - ref| / |ref|`` in L2 norms."""
+    a = np.asarray(logits, np.float64)
+    b = np.asarray(ref_rows, np.float64)
+    return np.linalg.norm(a - b, axis=-1) / np.linalg.norm(b, axis=-1)

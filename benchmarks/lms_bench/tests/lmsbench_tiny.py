@@ -37,24 +37,8 @@ SERVE_BATCHES = [
     {"prompts": [16, 16, 8, 8, 8, 4, 4, 4], "new": [2, 2, 4, 4, 6, 6, 8, 8]}]
 
 
-# the cells the tests drive, with the files they are made of; a cell kept
-# out of BENCHMARK.json still runs from its files here
-CELLS = {"granite-3-8b.train.dash8": ("granite-3-8b-l2", "train_dash8"),
-         "granite-3-8b.train.unmonitored": ("granite-3-8b-l2",
-                                            "train_unmonitored"),
-         "mixtral-8x7b.serve.closed8": ("mixtral-8x7b-l2", "serve_closed8")}
-
-
 def cell(name: str) -> bench.Cell:
-    spec = bench.load_spec()
-    if name not in {w["name"] for w in spec["workloads"]}:
-        config, traffic = CELLS[name]
-        spec["workloads"].append({"name": name, "config": config,
-                                  "traffic": traffic, "chips": 1})
-        spec["configs"].append(
-            {"name": config,
-             "file": f"benchmarks/lms_bench/configs/{config}.json"})
-    c = bench.load_cell(name, spec)
+    c = bench.load_cell(name)
     c.traffic = json.loads(json.dumps(c.traffic))
     if c.traffic["generator"] == "train":
         c.config = json.loads(json.dumps(TRAIN))

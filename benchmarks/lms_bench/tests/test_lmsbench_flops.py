@@ -56,3 +56,21 @@ def test_moe_counts_only_routed_experts():
     c4 = dict(c, num_hidden_layers=4)
     assert moe.request_flops(c4, 10, 3) > 1.9 * moe.request_flops(c, 10, 3) \
         - 2 * d * 32000 * 12
+
+
+def test_mixtral_decode_step_bytes_at_published_widths():
+    """Every held weight in bfloat16 (all 8 experts), the head over the
+    real vocabulary, 8 embedding rows, and 8 rows' keys and values at
+    positions 0..1023 of both layers."""
+    c = _conf("mixtral-8x7b-l2")
+    d, ff = 4096, 14336
+    attn = 4096 * 32 * 128 + 2 * 4096 * 8 * 128 + 32 * 128 * 4096
+    layer = attn + d * 8 + 8 * 3 * d * ff + 2 * d
+    assert layer == 1_451_270_144
+    weights = 2 * layer + d + d * 32000 + 8 * d
+    kv = 2 * 2 * 8 * 128 * 1024 * 8
+    assert moe.decode_step_bytes(c, 8, 1023) == 2 * (weights + kv)
+    assert moe.decode_step_bytes(c, 8, 1023) == 6_134_407_168
+    # one position more adds each row's key and value in both layers
+    assert moe.decode_step_bytes(c, 8, 1024) - \
+        moe.decode_step_bytes(c, 8, 1023) == 2 * 2 * 2 * 8 * 128 * 8
