@@ -99,6 +99,12 @@ def check_devices(chips: int):
     return devs[:chips]
 
 
+def memory_peak(devices) -> int:
+    """``peak_bytes_in_use`` of the fullest of the cell's devices."""
+    return max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devices)
+
+
 def peaks(device_kind: str) -> dict:
     table = _read_json(HERE / "peaks.json")
     if device_kind not in table:

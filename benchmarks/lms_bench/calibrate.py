@@ -42,7 +42,7 @@ def main(argv=None) -> int:
     from benchmarks.lms_bench import bench
     cell = bench.load_cell(args.workload)
     try:
-        bench.check_devices(cell.chips)
+        devices = bench.check_devices(cell.chips)
     except bench.NoDevice as e:
         print(f"lms_bench: {e}", file=sys.stderr)
         return 2
@@ -59,7 +59,8 @@ def main(argv=None) -> int:
         stack = MonitoringStack.inprocess(
             out_dir=str(bench.OUT_DIR / "calibrate" / "lms"))
         try:
-            r = generator.calibrate_seed(cell, seed, stack, control)
+            r = generator.calibrate_seed(cell, seed, stack, control,
+                                         devices=devices)
         finally:
             stack.close()
         r["elapsed_s"] = time.monotonic() - T_PROCESS

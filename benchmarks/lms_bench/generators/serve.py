@@ -424,8 +424,7 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
             um.flush()
         if w.ann is not None:
             jax.profiler.stop_trace()
-        mem = devices[0].memory_stats() or {}
-        peak = int(mem.get("peak_bytes_in_use", 0))
+        peak = bench.memory_peak(devices)
     finally:
         if spans is not None:
             spans.uninstall()
@@ -526,9 +525,10 @@ def check_pass(cell: bench.Cell, params, seed: int, stack, plant=None,
 
 
 def calibrate_seed(cell: bench.Cell, seed: int, stack,
-                   control: bool = True) -> dict:
+                   control: bool = True, devices=None) -> dict:
     """The program's readings on one seed; with ``control``, the float8
-    control's and each planted fault's too."""
+    control's and each planted fault's too.  Serving runs on the default
+    device whatever ``devices`` holds."""
     import jax.numpy as jnp
     params = _weights(cell, seed)
     prog = check_pass(cell, params, seed, stack,

@@ -11,6 +11,13 @@ the first step that ends ``--seconds`` later: the callback then raises
 traffic mix, dashboard viewers query the stack's ``/query/v2`` for the
 whole window.
 
+A cell on several chips runs the job over them as ``launch/train.py``
+runs a multi-device job: the mesh of ``launch/mesh.make_mesh_for`` over
+the chip count (4 chips: data 2 x model 2), the ``train`` sharding rules,
+their partition constraints and the train bundle's input shardings.  The
+reference is then split over the same devices.  On one chip the job gets
+none of these, as the launcher's does.
+
 The loop does not hand its state to the callback, so the callback reads
 ``params`` and ``opt_state`` from the loop's frame.
 """
@@ -92,6 +99,22 @@ def _leaf_norm_fn():
     return norms
 
 
+def mesh_args(cfg, tcfg, shape, devices) -> dict:
+    """``train()``'s ``mesh``, ``pc`` and ``in_shardings`` for a job on
+    several devices, built as ``launch/train.py`` builds them; none for
+    one device."""
+    if devices is None or len(devices) < 2:
+        return {}
+    from repro.launch.mesh import make_mesh_for
+    from repro.launch.steps import build_train_bundle, make_pc
+    from repro.parallel.sharding import rules_for
+    mesh = make_mesh_for(len(devices))
+    rules = rules_for("train")
+    return {"mesh": mesh, "pc": make_pc(rules, mesh),
+            "in_shardings": build_train_bundle(cfg, shape, tcfg, mesh,
+                                               rules).in_shardings}
+
+
 def _loop_locals():
     """The train loop's frame, two calls up from the step callback."""
     frame = sys._getframe(2)
@@ -102,9 +125,10 @@ def _loop_locals():
 
 
 def warmup(cell: bench.Cell, seed: int, on_window=None, stack=None,
-           seconds: float = 0.0, trace_dir=None):
-    """Set up and run the job.  Returns the capture of the warm-up steps
-    and, when ``on_window`` is given, of the window's step stamps."""
+           seconds: float = 0.0, trace_dir=None, devices=None):
+    """Set up and run the job over ``devices`` (the default device when
+    ``None``).  Returns the capture of the warm-up steps and, when
+    ``on_window`` is given, of the window's step stamps."""
     import jax
     from repro.configs import ShapeConfig
     from repro.train.loop import train
@@ -153,7 +177,8 @@ def warmup(cell: bench.Cell, seed: int, on_window=None, stack=None,
     try:
         train(cfg, tcfg, shape, stack=stack, hosts=[JOB_HOST],
               step_callback=callback, user="bench",
-              job_id=f"lms-bench-{cell.name}", markers=traffic["markers"])
+              job_id=f"lms-bench-{cell.name}", markers=traffic["markers"],
+              **mesh_args(cfg, tcfg, shape, devices))
     except WindowClosed:
         pass
     return cap
@@ -209,30 +234,34 @@ def readings(prog, ref, diff=None) -> dict:
     return out
 
 
-def reference(conf, traffic, seed31, others=(), log=None,
+def reference(conf, traffic, seed31, devices, others=(), log=None,
               keep_params=False, **kw) -> dict:
-    """The reference's first steps; ``others`` are parameter leaves (in
-    the reference's order) of runs to hold against its parameters."""
+    """The reference's first steps, split over the cell's ``devices``;
+    ``others`` are parameter leaves (in the reference's order) of runs to
+    hold against its parameters."""
     import jax
     tdict = dict(conf["train"])
     ref = dense_train.run(conf, tdict, seed31, traffic["batch"],
                           traffic["seq_len"], TOTAL_STEPS,
-                          steps=traffic["warmup_steps"], log=log, **kw)
+                          steps=traffic["warmup_steps"], log=log,
+                          devices=devices, **kw)
     params = ref.pop("params")
     if keep_params:
         ref["params"] = jax.device_get(jax.tree.leaves(params))
-    ref["diff_norms"] = [dense_train.diff_norms(params, o) for o in others]
-    ref["change_norms"] = dense_train.change_norms(conf, seed31, params)
+    ref["diff_norms"] = [dense_train.diff_norms(params, o, devices)
+                         for o in others]
+    ref["change_norms"] = dense_train.change_norms(conf, seed31, params,
+                                                   devices)
     if log:
         log("reference change norms done")
     return ref
 
 
-def program_readout(conf, seed31, cap) -> dict:
+def program_readout(conf, seed31, cap, devices) -> dict:
     return {"losses": cap["losses"], "grad_norms": cap["grad_norms"],
             "params": cap["params"],
             "change_norms": dense_train.change_norms(conf, seed31,
-                                                     cap["params"])}
+                                                     cap["params"], devices)}
 
 
 # --------------------------------------------------------------------------
@@ -285,7 +314,8 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     train_thread = threading.get_ident()
     try:
         cap = warmup(cell, seed, on_window=on_window, stack=stack,
-                     seconds=seconds, trace_dir=trace_dir if trace else None)
+                     seconds=seconds, trace_dir=trace_dir if trace else None,
+                     devices=devices)
         t_end = cap["t_end"]
         if spans is not None:
             spans.active = False
@@ -293,8 +323,7 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
             else []
         if cap["trace"] is not None:
             jax.profiler.stop_trace()
-        mem = devices[0].memory_stats() or {}
-        peak = int(mem.get("peak_bytes_in_use", 0))
+        peak = bench.memory_peak(devices)
         checks, attempted, failed, e2e, ctx = [], 0, 0, {}, {}
 
         t_start, stamps = cap["t_start"], cap["stamps"]
@@ -338,12 +367,12 @@ def run(cell: bench.Cell, seed: int, seconds: float, trace: bool,
     bench.log(f"window closed, stack checked at "
               f"{time.monotonic() - t_process:.1f} s")
     s31 = bench.seed31(seed)
-    prog = program_readout(cell.config, s31, cap)
+    prog = program_readout(cell.config, s31, cap, devices)
     cap = None
     gc.collect()
     bench.log(f"program read out at {time.monotonic() - t_process:.1f} s")
-    ref = reference(cell.config, traffic, s31, others=[prog["params"]],
-                    log=bench.log)
+    ref = reference(cell.config, traffic, s31, devices,
+                    others=[prog["params"]], log=bench.log)
     r = readings(prog, ref, diff=ref["diff_norms"][0])
     bench.log(f"reference compared at {time.monotonic() - t_process:.1f} s")
     checks = [bench.Check(n, float(v), cell.limits[n]) for n, v in
@@ -381,30 +410,33 @@ def _points_lost(stack, points) -> int:
 
 
 def calibrate_seed(cell: bench.Cell, seed: int, stack,
-                   control: bool = True) -> dict:
+                   control: bool = True, devices=None) -> dict:
     """The program's warm-up steps as a run drives them, then the
     reference, the float8 control and the reference with half of the
     batch left out (a planted fault), each compared with the reference by
-    the cell's own numbers.  A state left unchanged reads 1 on
-    ``change_norm_gap`` by construction and needs no run."""
+    the cell's own numbers; the job and the references run over
+    ``devices`` (the default device when ``None``).  A state left
+    unchanged reads 1 on ``change_norm_gap`` by construction and needs no
+    run."""
     import jax.numpy as jnp
     s31 = bench.seed31(seed)
-    cap = warmup(cell, seed, stack=stack)
-    prog = program_readout(cell.config, s31, cap)
+    cap = warmup(cell, seed, stack=stack, devices=devices)
+    prog = program_readout(cell.config, s31, cap, devices)
     cap = None
     gc.collect()
     traffic = cell.traffic
     log = bench.log
     ctl = half = None
     if control:
-        ctl = reference(cell.config, traffic, s31, log=log,
+        ctl = reference(cell.config, traffic, s31, devices, log=log,
                         mm_dtype=jnp.float8_e4m3fn, keep_params=True)
-        half = reference(cell.config, traffic, s31, log=log,
+        half = reference(cell.config, traffic, s31, devices, log=log,
                          rows=slice(0, traffic["batch"] // 2),
                          keep_params=True)
     others = [prog["params"]] + ([ctl.pop("params"), half.pop("params")]
                                  if control else [])
-    ref = reference(cell.config, traffic, s31, others=others, log=log)
+    ref = reference(cell.config, traffic, s31, devices, others=others,
+                    log=log)
     d = ref["diff_norms"]
     out = {"seed": seed,
            "program": readings(prog, ref, diff=d[0]),

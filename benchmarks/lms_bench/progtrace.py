@@ -282,21 +282,26 @@ def idle_by_phase(events: dict):
     return by_phase, steps
 
 
-def busy_by_scope(events: dict):
-    """``{scope or None: busy ns}``: each busy instant of the window given
-    to the innermost op covering it, and that op's scope; ``None`` when no
-    op carries a scope (a program without scopes)."""
+def busy_by(events: dict, key):
+    """``{key(op): busy ns}``: each busy instant of the window given to
+    the innermost op covering it."""
     lo, hi = events["window"]
     ops = events["ops"]
-    if not any(op[3] for op in ops):
-        return None
     pieces = innermost([(s, e, i) for i, (_, s, e, _) in enumerate(ops)],
                        lo, hi)
     out = {}
     for s, e, i in pieces:
-        scope = ops[i][3]
-        out[scope] = out.get(scope, 0.0) + (e - s)
+        k = key(ops[i])
+        out[k] = out.get(k, 0.0) + (e - s)
     return out
+
+
+def busy_by_scope(events: dict):
+    """``{scope or None: busy ns}`` (``busy_by`` the op's scope); ``None``
+    when no op carries a scope (a program without scopes)."""
+    if not any(op[3] for op in events["ops"]):
+        return None
+    return busy_by(events, lambda op: op[3])
 
 
 def spans_in_window(events: dict, name: str):

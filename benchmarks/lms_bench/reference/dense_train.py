@@ -4,10 +4,19 @@ Written from the configuration alone and imports nothing of the program:
 the same equations (RMSNorm, rotary GQA attention, SwiGLU MLP, tied LM
 head over the padded vocabulary with the padded logits masked out of the
 loss, global-norm clipping, AdamW with linear warm-up and cosine decay)
-in float32 at ``highest`` matmul precision, with no kernels, sharding or
-fused step.  Attention and the loss are computed in blocks of queries and
-of tokens, and every layer is rematerialised, so that the whole step fits
-beside the optimizer state on one chip.
+in plain ``jax.numpy``, float32 at ``highest`` matmul precision, with no
+kernels and no fused step.  Attention and the loss are computed in blocks
+of queries and of tokens, and every layer is rematerialised, so that the
+whole step fits beside the optimizer state.
+
+``devices`` (the default device when none are given) places the arrays
+and nothing else.  The parameters, both AdamW moments and the gradients
+are split over a 1-D mesh of those devices, each leaf along its largest
+axis that the device count divides (replicated where none does), and the
+rows of the batch where the count divides them; on several devices no
+leaf is whole on one of them, and on one device nothing is split.  The
+arithmetic is not partitioned by hand: XLA partitions the same program,
+so only the order of the reductions across devices may differ.
 
 The weights and the data are remade from the seed by the same recipes the
 program states (normal weights with a per-leaf key folded from the leaf's
@@ -27,6 +36,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh, NamedSharding
+from jax.sharding import PartitionSpec as P
 
 NEG = -1e9
 
@@ -84,6 +95,46 @@ def leaf_paths(cfg: dict) -> list:
     return [("/".join(str(p) for p in path), desc) for path, desc in flat]
 
 
+# --------------------------------------------------------------------------
+# placement over the devices
+# --------------------------------------------------------------------------
+
+
+def _devices(devices) -> tuple:
+    """The devices to place the arrays on: those given, or the default."""
+    return tuple(devices) if devices else (jax.devices()[0],)
+
+
+def _mesh(devices: tuple) -> Mesh:
+    return Mesh(np.array(devices), ("d",), axis_types=(AxisType.Auto,))
+
+
+def leaf_sharding(shape, devices: tuple) -> NamedSharding:
+    """Split along the largest axis that the device count divides (the
+    first of equal ones), or replicated where none does."""
+    n = len(devices)
+    axes = [i for i, size in enumerate(shape) if size % n == 0]
+    spec = [None] * len(shape)
+    if axes:
+        spec[max(axes, key=lambda i: (shape[i], -i))] = "d"
+    return NamedSharding(_mesh(devices), P(*spec))
+
+
+def row_sharding(rows: int, devices: tuple) -> NamedSharding:
+    """Tokens and labels: split by row where the device count divides the
+    rows, replicated where it does not."""
+    split = rows % len(devices) == 0
+    return NamedSharding(_mesh(devices), P("d" if split else None))
+
+
+def place(leaves, devices) -> list:
+    """Leaves (host or device arrays) onto the devices, each split by
+    ``leaf_sharding``."""
+    devs = _devices(devices)
+    return jax.device_put(list(leaves), [leaf_sharding(np.shape(x), devs)
+                                         for x in leaves])
+
+
 def init_leaf(seed: int, path: str, desc) -> jax.Array:
     shape, kind, std = desc
     if kind == "ones":
@@ -93,16 +144,28 @@ def init_leaf(seed: int, path: str, desc) -> jax.Array:
     return jax.random.normal(key, shape, jnp.float32) * std
 
 
-def init_params(cfg: dict, seed: int) -> dict:
-    return _init(seed, _cfg_key(cfg))
+def init_state(cfg: dict, seed: int, devices: tuple) -> tuple:
+    """(params, m, v) made split over ``devices`` in one jitted call."""
+    return _init(_cfg_key(cfg), devices)(seed, _cfg_key(cfg))
 
 
-@partial(jax.jit, static_argnums=(1,))
-def _init(seed, cfg_key):
-    cfg = dict(cfg_key)
-    return jax.tree.unflatten(
-        jax.tree.structure(leaf_shapes(cfg), is_leaf=_is_desc),
-        [init_leaf(seed, p, d) for p, d in leaf_paths(cfg)])
+@lru_cache(maxsize=None)
+def _init(cfg_key: tuple, devices: tuple):
+    def init(seed, cfg_key):
+        cfg = dict(cfg_key)
+        params = jax.tree.unflatten(
+            jax.tree.structure(leaf_shapes(cfg), is_leaf=_is_desc),
+            [init_leaf(seed, p, d) for p, d in leaf_paths(cfg)])
+        return (params, jax.tree.map(jnp.zeros_like, params),
+                jax.tree.map(jnp.zeros_like, params))
+    sh = shardings(dict(cfg_key), devices)
+    return jax.jit(init, static_argnums=(1,), out_shardings=(sh, sh, sh))
+
+
+def shardings(cfg: dict, devices: tuple) -> dict:
+    """``leaf_sharding`` of every parameter, in the parameters' tree."""
+    return jax.tree.map(lambda d: leaf_sharding(d[0], devices),
+                        leaf_shapes(cfg), is_leaf=_is_desc)
 
 
 def batch(cfg: dict, seed: int, step: int, rows: int, seq_len: int,
@@ -258,19 +321,21 @@ def _leaf_norms(tree) -> list:
     return [jnp.sqrt(jnp.sum(jnp.square(x))) for x in jax.tree.leaves(tree)]
 
 
-def make_step(cfg: dict, tcfg: dict, mm_dtype=None):
+def make_step(cfg: dict, tcfg: dict, devices: tuple, rows: int,
+              mm_dtype=None):
+    """The jitted step; its inputs and outputs keep the split of
+    ``shardings`` and ``row_sharding(rows)`` over ``devices``."""
     return _make_step(_cfg_key(cfg) + (("rms_norm_eps", cfg["rms_norm_eps"]),
                                        ("rope_theta", cfg["rope_theta"])),
-                      tuple(sorted(tcfg.items())), mm_dtype)
+                      tuple(sorted(tcfg.items())), mm_dtype, devices, rows)
 
 
 @lru_cache(maxsize=None)
-def _make_step(cfg_key: tuple, tcfg_key: tuple, mm_dtype):
+def _make_step(cfg_key: tuple, tcfg_key: tuple, mm_dtype, devices, rows):
     cfg, tcfg = dict(cfg_key), dict(tcfg_key)
     b1, b2, eps = tcfg["beta1"], tcfg["beta2"], tcfg["eps"]
     wd, clip = tcfg["weight_decay"], tcfg["grad_clip_norm"]
 
-    @partial(jax.jit, donate_argnums=(0, 1, 2))
     def step(params, m, v, count, lr, tokens, labels):
         with jax.default_matmul_precision("highest"):
             val, g = jax.value_and_grad(loss)(params, tokens, labels, cfg,
@@ -288,19 +353,24 @@ def _make_step(cfg_key: tuple, tcfg_key: tuple, mm_dtype):
                                         + wd * p), params, m, v)
         return params, m, v, val, _leaf_norms(g)
 
-    return step
+    sh = shardings(cfg, devices)
+    one = NamedSharding(_mesh(devices), P())
+    tok = row_sharding(rows, devices)
+    return jax.jit(step, donate_argnums=(0, 1, 2),
+                   in_shardings=(sh, sh, sh, one, one, tok, tok),
+                   out_shardings=(sh, sh, sh, one, one))
 
 
 def run(cfg: dict, tcfg: dict, seed: int, batch_size: int, seq_len: int,
         total_steps: int, steps: int = 3, mm_dtype=None,
-        rows: Optional[slice] = None, log=None) -> dict:
+        rows: Optional[slice] = None, log=None, devices=None) -> dict:
     """The first ``steps`` steps from the seed.  Returns the losses, the
     per-leaf norms of the first (clipped) gradient and the parameters
-    after the last step."""
-    params = init_params(cfg, seed)
-    m = jax.tree.map(jnp.zeros_like, params)
-    v = jax.tree.map(jnp.zeros_like, params)
-    step_fn = make_step(cfg, tcfg, mm_dtype)
+    after the last step, placed over ``devices``."""
+    devs = _devices(devices)
+    n_rows = len(range(batch_size)[rows]) if rows is not None else batch_size
+    params, m, v = init_state(cfg, seed, devs)
+    step_fn = make_step(cfg, tcfg, devs, n_rows, mm_dtype)
     losses, grad_norms = [], None
     for i in range(steps):
         tokens, labels = batch(cfg, seed, i, batch_size, seq_len)
@@ -308,8 +378,7 @@ def run(cfg: dict, tcfg: dict, seed: int, batch_size: int, seq_len: int,
             tokens, labels = tokens[rows], labels[rows]
         lr = learning_rate(tcfg, total_steps, i)
         params, m, v, val, gn = step_fn(
-            params, m, v, jnp.float32(i + 1), jnp.float32(lr),
-            jnp.asarray(tokens), jnp.asarray(labels))
+            params, m, v, jnp.float32(i + 1), jnp.float32(lr), tokens, labels)
         losses.append(float(val))
         if log is not None:
             log(f"reference step {i + 1} done")
@@ -319,10 +388,11 @@ def run(cfg: dict, tcfg: dict, seed: int, batch_size: int, seq_len: int,
     return {"losses": losses, "grad_norms": grad_norms, "params": params}
 
 
-def diff_norms(params_ref, params_other) -> list:
+def diff_norms(params_ref, params_other, devices=None) -> list:
     """Per-leaf norm of (reference parameters - another run's leaves, in
-    the reference's order; host arrays are fine), in one jitted call."""
-    other = jax.device_put(list(params_other))
+    the reference's order; host arrays are fine), in one jitted call, the
+    other run's leaves placed as ``place`` does."""
+    other = place(params_other, devices)
     return [float(x) for x in _diff(jax.tree.leaves(params_ref), other)]
 
 
@@ -332,11 +402,12 @@ def _diff(a, b):
             for x, y in zip(a, b)]
 
 
-def change_norms(cfg: dict, seed: int, params_after) -> list:
+def change_norms(cfg: dict, seed: int, params_after, devices=None) -> list:
     """Per-leaf norm of (params_after - the seed's initial params), the
-    initial leaves remade on the device, in one jitted call;
-    ``params_after`` may be host arrays in the reference's layout."""
-    leaves = jax.device_put(list(jax.tree.leaves(params_after)))
+    initial leaves remade on the device(s), in one jitted call;
+    ``params_after`` may be host arrays in the reference's layout, and is
+    placed as ``place`` does."""
+    leaves = place(jax.tree.leaves(params_after), devices)
     return [float(x) for x in _change(leaves, seed, _cfg_key(cfg))]
 
 

@@ -43,36 +43,40 @@ def test_configs_state_their_source_and_cuts():
 
 
 def test_a_new_cell_is_new_files_only(tmp_path):
-    """A cell, its traffic mix, its limits and a per-layer metric, added as
-    files beside a copy of the spec, load without editing any file."""
+    """Cells, their traffic mixes, their limits and a per-layer metric,
+    added as files beside a copy of the spec, load without editing any
+    file: a one-chip cell with a larger batch, and the same mix on four
+    chips, which the train generator runs over a mesh of them."""
     for sub in ("traffic", "limits", "metrics"):
         (tmp_path / sub).mkdir()
     spec = json.loads(json.dumps(SPEC))
-    spec["workloads"].append({"name": "granite-3-8b.train.b4",
-                              "config": "granite-3-8b-l2",
-                              "traffic": "train_b4", "chips": 1,
-                              "why": "a larger batch"})
-    spec["per_layer"].append({"name": "steps_seen", "unit": "steps",
-                              "better": "higher", "source": "host_clock",
-                              "layer": "device", "moves": "train_tokens_per_s",
-                              "workloads": ["granite-3-8b.train.b4"]})
-    for m in spec["end_to_end"]:
-        if m["name"] == "train_tokens_per_s":
-            m["workloads"].append("granite-3-8b.train.b4")
+    new = {"granite-3-8b.train.b4": 1, "granite-3-8b.train.mesh2x2": 4}
     traffic = json.loads((HERE / "traffic" / "train_unmonitored.json")
                          .read_text())
     traffic["batch"] = 4
     (tmp_path / "traffic" / "train_b4.json").write_text(json.dumps(traffic))
-    (tmp_path / "limits" / "granite-3-8b.train.b4.json").write_text(
-        json.dumps({"loss_gap": {"limit": 1e-3}}))
+    for name, chips in new.items():
+        spec["workloads"].append({"name": name, "config": "granite-3-8b-l2",
+                                  "traffic": "train_b4", "chips": chips,
+                                  "why": "a larger batch"})
+        (tmp_path / "limits" / f"{name}.json").write_text(
+            json.dumps({"loss_gap": {"limit": 1e-3}}))
+    spec["per_layer"].append({"name": "steps_seen", "unit": "steps",
+                              "better": "higher", "source": "host_clock",
+                              "layer": "device", "moves": "train_tokens_per_s",
+                              "workloads": list(new)})
+    for m in spec["end_to_end"]:
+        if m["name"] == "train_tokens_per_s":
+            m["workloads"].extend(new)
     (tmp_path / "metrics" / "steps_seen.py").write_text(
         "def read(ctx):\n    return ctx.get('steps')\n")
-    cell = bench.load_cell("granite-3-8b.train.b4", spec, base=tmp_path)
-    assert cell.traffic["batch"] == 4
-    assert cell.limits == {"loss_gap": 1e-3}
-    assert [m["name"] for m in cell.end_to_end] == ["train_tokens_per_s",
-                                                    "setup_s"]
-    assert [m["name"] for m in cell.per_layer] == ["steps_seen"]
+    for name, chips in new.items():
+        cell = bench.load_cell(name, spec, base=tmp_path)
+        assert cell.traffic["batch"] == 4 and cell.chips == chips
+        assert cell.limits == {"loss_gap": 1e-3}
+        assert [m["name"] for m in cell.end_to_end] == ["train_tokens_per_s",
+                                                        "setup_s"]
+        assert [m["name"] for m in cell.per_layer] == ["steps_seen"]
     reader = bench.metric_reader("steps_seen", base=tmp_path)
     assert reader({"steps": 7}) == 7 and reader({}) is None
 
