@@ -56,6 +56,13 @@ def make_serve_fns(cfg: ModelConfig, *, pc=None, donate_cache: bool = True):
     return prefill, decode
 
 
+def greedy_next(logits, pos):
+    """The greedy choice of each row as the next decode step's input, a
+    (B, 1) int32 token column, and that step's position ``pos + 1``: both
+    stay on the device, so the next step needs nothing from the host."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None], pos + 1
+
+
 @dataclass
 class Request:
     rid: int
@@ -75,6 +82,14 @@ class ServingEngine:
     token lands at position plen-1 (where the first sampled logit is read);
     the left padding is BOS (token 0) and is attended — the demo-engine
     simplification vs. per-row attention masks, documented here.
+
+    Lagged readback: each step's greedy token and position stay on the
+    device (``greedy_next``) as the next decode step's input, and the host
+    reads step *s*'s tokens only after it has dispatched step *s+1*.  The
+    host runs one step ahead of the device, never more, so the device
+    always holds the next decode step in its queue while the host waits
+    on a read and runs the row loop.  Prefill's first token is still read
+    at once: it is the time to first token.
     """
 
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
@@ -95,6 +110,7 @@ class ServingEngine:
         prefill, decode = make_serve_fns(cfg)
         self.prefill = jax.jit(prefill) if jit else prefill
         self.decode = jax.jit(decode, donate_argnums=(1,)) if jit else decode
+        self.greedy_next = jax.jit(greedy_next) if jit else greedy_next
 
     # -- request api -----------------------------------------------------------
 
@@ -109,6 +125,18 @@ class ServingEngine:
     def _metric(self, name, value, **tags):
         if self.um is not None:
             self.um.metric(name, value, tags=tags or None)
+
+    @staticmethod
+    def _read_tokens(reqs, tokens) -> float:
+        """Appends one step's tokens (B, 1) to the rows still short of
+        their answer; returns the seconds the host waited for them."""
+        t0 = time.monotonic()
+        tk = np.asarray(tokens)
+        waited = time.monotonic() - t0
+        for i, r in enumerate(reqs):
+            if len(r.output) < r.max_new_tokens:
+                r.output.append(int(tk[i, 0]))
+        return waited
 
     # -- batch step ---------------------------------------------------------------
 
@@ -132,38 +160,37 @@ class ServingEngine:
             cache = init_cache(self.cfg, b, self.max_len)
             last_logits, cache = self.prefill(self.params,
                                               jnp.asarray(toks), cache)
-            next_tok = jnp.argmax(last_logits, axis=-1)
-            tk0 = np.asarray(next_tok)       # sync: real prefill time
+            tok, pos = self.greedy_next(last_logits, jnp.int32(plen - 1))
+            tk0 = np.asarray(tok)            # sync: real prefill time
         prefill_s = time.monotonic() - t0
         self._metric("serve_prefill", {"batch": b, "prompt_len": plen,
                                        "prefill_time_s": prefill_s})
         now = time.monotonic()
         for i, r in enumerate(reqs):
             r.first_token_at = now
-            r.output.append(int(tk0[i]))
+            r.output.append(int(tk0[i, 0]))
 
         max_new = max(r.max_new_tokens for r in reqs)
-        pos = plen
         t_dec = time.monotonic()
+        wait_s = 0.0
         dec_region = m.region("serve:decode") if m else nullcontext()
         with dec_region:
-            for step in range(max_new - 1):
-                logits, cache = self.decode(self.params, cache,
-                                            next_tok[:, None],
-                                            jnp.int32(pos))
-                next_tok = jnp.argmax(logits, axis=-1)
-                pos += 1
-                tk = np.asarray(next_tok)
-                for i, r in enumerate(reqs):
-                    if len(r.output) < r.max_new_tokens:
-                        r.output.append(int(tk[i]))
+            lagged = None           # the step before's tokens, not yet read
+            for _ in range(max_new - 1):
+                logits, cache = self.decode(self.params, cache, tok, pos)
+                tok, pos = self.greedy_next(logits, pos)
+                if lagged is not None:
+                    wait_s += self._read_tokens(reqs, lagged)
+                lagged = tok
+            if lagged is not None:
+                wait_s += self._read_tokens(reqs, lagged)
             n_tok = sum(len(r.output) for r in reqs)
             if m:
                 dec_region.add(tokens=float(n_tok - b))
         decode_s = time.monotonic() - t_dec
         self._metric("serve_decode", {
             "batch": b, "new_tokens": n_tok,
-            "decode_time_s": decode_s,
+            "decode_time_s": decode_s, "readback_wait_s": wait_s,
             "tokens_per_s": n_tok / max(decode_s, 1e-9)})
         done = []
         now = time.monotonic()

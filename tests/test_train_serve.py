@@ -135,6 +135,127 @@ def test_serving_masks_vocab_padding():
     assert all(0 <= t < cfg.vocab_size for r in done for t in r.output)
 
 
+# rows that differ in prompt length and answer length, served as a batch of
+# three and a batch of two (max_batch=3)
+MIXED = [(3, 2), (9, 6), (5, 4), (7, 5), (4, 1)]
+SERVE_CFGS = ["lms-demo", "mixtral-8x7b"]
+
+
+def _mixed_engine(arch, jit):
+    cfg = get_config(arch, smoke=True)
+    params = init_model_params(cfg, seed=0)
+    eng = ServingEngine(cfg, params, max_batch=3, max_len=32, jit=jit)
+    rng = np.random.default_rng(0)
+    prompts = [(rng.integers(1, cfg.vocab_size, n, dtype=np.int32), m)
+               for n, m in MIXED]
+    for p, m in prompts:
+        eng.submit(p, max_new_tokens=m)
+    return cfg, params, eng, prompts
+
+
+def _synchronous_greedy(cfg, params, batch, jit):
+    """The batch decoded one step at a time, each step's argmax read to
+    the host before the next step is dispatched; rows laid out as the
+    engine lays them out."""
+    from repro.models.transformer import init_cache
+    from repro.serve.engine import make_serve_fns
+    prefill, decode = make_serve_fns(cfg)
+    if jit:
+        prefill, decode = jax.jit(prefill), jax.jit(decode)
+    plen = max(len(p) for p, _ in batch)
+    toks = np.zeros((len(batch), plen), np.int32)
+    for i, (p, _) in enumerate(batch):
+        toks[i, plen - len(p):] = p
+    logits, cache = prefill(params, jnp.asarray(toks),
+                            init_cache(cfg, len(batch), 32))
+    steps = [np.asarray(jnp.argmax(logits, axis=-1))]
+    for s in range(max(m for _, m in batch) - 1):
+        logits, cache = decode(params, cache,
+                               jnp.asarray(steps[-1])[:, None],
+                               jnp.int32(plen + s))
+        steps.append(np.asarray(jnp.argmax(logits, axis=-1)))
+    return [[int(t[i]) for t in steps[:m]] for i, (_, m) in enumerate(batch)]
+
+
+@pytest.mark.parametrize("jit", [True, False], ids=["jit", "eager"])
+@pytest.mark.parametrize("arch", SERVE_CFGS)
+def test_lagged_readback_gives_the_synchronous_tokens(arch, jit):
+    cfg, params, eng, prompts = _mixed_engine(arch, jit)
+    done = eng.run_until_empty()
+    want = (_synchronous_greedy(cfg, params, prompts[:3], jit)
+            + _synchronous_greedy(cfg, params, prompts[3:], jit))
+    assert [r.output for r in done] == want
+    assert [len(r.output) for r in done] == [m for _, m in MIXED]
+
+
+class _Tokens:
+    """One step's tokens as the engine holds them; logs the host's read."""
+
+    def __init__(self, arr, step, log):
+        self.arr, self.step, self.log = arr, step, log
+
+    def __array__(self, dtype=None, copy=None):
+        self.log.append(("read", self.step))
+        return np.asarray(self.arr, dtype)
+
+
+@pytest.mark.parametrize("arch", SERVE_CFGS)
+def test_decode_calls_and_the_one_step_lag(arch):
+    """``decode`` is called max_new - 1 times a batch with (B, 1) int32
+    tokens and an int32 scalar position, and the host reads step s's
+    tokens only after it has called decode for step s + 1."""
+    _, _, eng, _ = _mixed_engine(arch, jit=True)
+    log, calls = [], []
+    decode, greedy_next = eng.decode, eng.greedy_next
+
+    def logged_next(logits, pos):
+        tok, pos = greedy_next(logits, pos)
+        step = sum(1 for e in log if e[0] == "decode")
+        return _Tokens(tok, step, log), pos
+
+    def logged_decode(params, cache, tokens, pos):
+        calls.append((tokens.arr.shape, tokens.arr.dtype, pos.shape,
+                      pos.dtype))
+        log.append(("decode", tokens.step + 1))
+        return decode(params, cache, tokens.arr, pos)
+
+    eng.decode, eng.greedy_next = logged_decode, logged_next
+    for b, m in ((3, 6), (2, 5)):
+        log.clear()
+        calls.clear()
+        eng.run_batch()
+        assert calls == [((b, 1), jnp.int32, (), jnp.int32)] * (m - 1)
+        # every step's tokens are read once, in order
+        assert [s for e, s in log if e == "read"] == list(range(m))
+        at = {e: i for i, e in enumerate(log)}
+        assert at[("read", 0)] < at[("decode", 1)]      # prefill's sync
+        for s in range(1, m - 1):
+            assert at[("decode", s + 1)] < at[("read", s)]
+        assert at[("decode", m - 1)] < at[("read", m - 1)]
+
+
+def test_serve_decode_point_carries_the_readback_wait(tmp_path):
+    cfg = get_config("lms-demo", smoke=True)
+    stack = MonitoringStack.inprocess(out_dir=str(tmp_path))
+    try:
+        with stack.job("serve2", user="u", hosts=["h0"]):
+            um = stack.usermetric(host="h0")
+            eng = ServingEngine(cfg, init_model_params(cfg, seed=0),
+                                max_batch=3, max_len=32, usermetric=um)
+            for n, m in MIXED:
+                eng.submit(np.arange(1, n + 1), max_new_tokens=m)
+            eng.run_until_empty()
+            um.flush()
+        (s,) = stack.backend.db("global").select(
+            "serve_decode", ["readback_wait_s", "decode_time_s"])
+    finally:
+        stack.close()
+    waits, times = s.values["readback_wait_s"], s.values["decode_time_s"]
+    assert len(waits) == len(times) == 2
+    for w, t in zip(waits, times):
+        assert 0 <= w <= t
+
+
 def test_straggler_finding_triggers_elastic_halt(tmp_path):
     """Monitoring is load-bearing: a sustained straggler finding (emitted by
     a simulated peer host) halts the loop so the launcher can restart
