@@ -16,7 +16,6 @@ from repro.configs.base import (
     available_archs,
     get_config,
     reduce_for_smoke,
-    supports_shape,
 )
 
 ASSIGNED_ARCHS = [
@@ -49,5 +48,4 @@ __all__ = [
     "available_archs",
     "get_config",
     "reduce_for_smoke",
-    "supports_shape",
 ]

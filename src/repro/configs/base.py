@@ -13,8 +13,7 @@ Shape sets (assignment): each architecture is paired with the LM shape set
     long_500k    seq_len=524288  global_batch=1     (long-context decode)
 
 ``decode_*``/``long_*`` lower ``serve_decode`` (one token against a KV cache of
-seq_len), not ``train_step``.  ``long_500k`` is only lowered for sub-quadratic
-architectures (SSM / hybrid / sliding-window); see ``supports_shape``.
+seq_len), not ``train_step``.
 """
 
 from __future__ import annotations
@@ -54,7 +53,7 @@ class MoEConfig:
     # crosses the mesh.  1 = single global dispatch.
     dispatch_groups: int = 1
     # "grouped" (GSPMD, default) | "a2a" (shard_map ragged all-to-all over
-    # the EP axis — §Perf; single-pod meshes, E % tp == 0)
+    # the EP axis; single-pod meshes, E % tp == 0)
     impl: str = "grouped"
 
 
@@ -184,13 +183,6 @@ class ModelConfig:
     def is_attention_free(self) -> bool:
         return self.attention_type == "none"
 
-    @property
-    def sub_quadratic(self) -> bool:
-        """True if the arch can decode at 500k context (assignment rule)."""
-        if self.family in ("ssm", "hybrid"):
-            return True
-        return self.sliding_window > 0
-
     def param_count(self) -> int:
         """Approximate parameter count (embedding + blocks), for 6ND math."""
         d = self.d_model
@@ -302,13 +294,6 @@ SHAPES = {
 }
 
 
-def supports_shape(cfg: ModelConfig, shape: ShapeConfig) -> bool:
-    """Assignment rule: long_500k only for sub-quadratic architectures."""
-    if shape.name == "long_500k" and not cfg.sub_quadratic:
-        return False
-    return True
-
-
 # --------------------------------------------------------------------------
 # Train / run config
 # --------------------------------------------------------------------------
@@ -328,10 +313,6 @@ class TrainConfig:
     num_microbatches: int = 1       # gradient accumulation
     remat_policy: str = "minimal"   # none | minimal | full
     grad_compression: str = "none"  # none | int8 | bf16  (DP all-reduce)
-    attn_impl: str = "auto"         # auto | masked | recursive | flash
-    scan_unroll: int = 1            # layer-scan unroll factor
-    grad_sync_dtype: str = "float32"  # float32 | bfloat16 DP reduction
-    seq_parallel: bool = False      # Megatron-SP residual sharding (§Perf)
     seed: int = 0
     # LMS monitoring
     monitor: bool = True

@@ -15,7 +15,7 @@ from typing import Optional
 
 from repro.core.analysis import (
     ANALYSIS_MEASUREMENT, Alert, AnalysisEngine, DEFAULT_TREE, Finding,
-    RooflineAnalyzer, RooflineResult, StreamAnalyzer, ThresholdRule,
+    StreamAnalyzer, ThresholdRule,
     classify_job, default_rules, evaluate_rules_on_db, load_alerts,
     load_job_report)
 from repro.core.dashboard import DashboardAgent
@@ -62,7 +62,7 @@ __all__ = [
     "PerfGroup", "Point", "QueryEngine", "QueryResult", "QuerySpec",
     "FINGERPRINT_KIND", "QUANTILE_AGGS", "QuantileSketch",
     "ROLLUP_AGGS", "RollupConfig",
-    "RooflineAnalyzer", "RooflineResult", "SeriesRollups", "SketchAgg",
+    "SeriesRollups", "SketchAgg",
     "ShardedDatabase", "StreamAnalyzer", "TSDBServer", "ThresholdRule",
     "UserMetric", "WindowAgg", "calibrate", "classify_job",
     "compile_formula",
@@ -133,7 +133,6 @@ class MonitoringStack:
         self.analysis_recovery = self.analysis.recover() \
             if (persist_dir and recover) else {}
         self.dashboards = DashboardAgent(self.backend, out_dir=out_dir)
-        self.roofline = RooflineAnalyzer()
         self.http: Optional[LMSHttpServer] = None
         if serve_http:
             self.http = LMSHttpServer(self.router).start()

@@ -1,6 +1,6 @@
 """Data-analysis methodology (paper §V) — the continuous analysis engine.
 
-Three analysis layers, exactly as the paper structures them:
+Two analysis layers, as the paper structures them:
 
 1. **Pathological-job detection** — simple rules over resource-utilization
    metrics using *thresholds and timeouts* (paper Fig. 4: FP rate and memory
@@ -37,9 +37,8 @@ Three analysis layers, exactly as the paper structures them:
    nodes) cannot certify either branch without data and classify as
    ``insufficient-data``.
 
-3. **RooflineAnalyzer** — the assignment's three-term roofline, computed per
-   (arch x shape x mesh) cell from the dry-run's compiled artifact.  It both
-   fills EXPERIMENTS.md §Roofline and feeds layer 2.
+(The per-region roofline is not computed here: the query engine prices it
+from marker work counters, ``repro.core.marker.roofline_spec``.)
 
 The ``analysis`` measurement schema (what :func:`load_alerts` /
 :func:`load_job_report` read back, also over HTTP or federated views):
@@ -67,7 +66,6 @@ from typing import Callable, Iterable, Optional
 from repro.core.fingerprint import (fingerprint_outliers, fingerprint_point,
                                     job_fingerprint, load_fingerprints)
 from repro.core.line_protocol import Point, now_ns
-from repro.core.perf_groups import HBM_BW, ICI_BW, PEAK_FLOPS
 from repro.core.tsdb import _tags_key
 
 ANALYSIS_MEASUREMENT = "analysis"
@@ -1293,84 +1291,3 @@ def classify_job(metrics: dict, tree: PatternNode = DEFAULT_TREE) -> dict:
     pattern, remedy, path, missing = tree.classify(dict(metrics))
     return {"pattern": pattern, "remedy": remedy, "path": path,
             "missing": missing}
-
-
-# ==========================================================================
-# 3. Roofline analyzer (assignment §Roofline; feeds the tree above)
-# ==========================================================================
-
-
-@dataclass
-class RooflineResult:
-    arch: str
-    shape: str
-    mesh: str
-    chips: int
-    compute_s: float
-    memory_s: float
-    collective_s: float
-    model_flops: float
-    hlo_flops: float
-    hbm_bytes: float
-    collective_bytes: float
-
-    @property
-    def terms(self) -> dict:
-        return {"compute": self.compute_s, "memory": self.memory_s,
-                "collective": self.collective_s}
-
-    @property
-    def dominant(self) -> str:
-        return max(self.terms, key=self.terms.get)
-
-    @property
-    def bound_s(self) -> float:
-        """Roofline lower bound on step time (max of the three terms)."""
-        return max(self.compute_s, self.memory_s, self.collective_s)
-
-    @property
-    def useful_flop_ratio(self) -> float:
-        return self.model_flops / self.hlo_flops if self.hlo_flops else 0.0
-
-    @property
-    def roofline_fraction(self) -> float:
-        """compute term / bound — 1.0 means perfectly compute-limited."""
-        return self.compute_s / self.bound_s if self.bound_s else 0.0
-
-    def fractions(self) -> dict:
-        tot = sum(self.terms.values()) or 1.0
-        return {f"{k}_frac": v / tot for k, v in self.terms.items()}
-
-    def classify(self, extra_metrics: Optional[dict] = None) -> dict:
-        m = {**self.fractions(),
-             "useful_flop_ratio": self.useful_flop_ratio,
-             "mfu": self.roofline_fraction,   # upper-bound MFU from terms
-             "data_stall_frac": 0.0, "straggler_skew": 0.0}
-        if extra_metrics:
-            m.update(extra_metrics)
-        return classify_job(m)
-
-
-class RooflineAnalyzer:
-    """Three-term roofline from dry-run artifacts (per-chip quantities)."""
-
-    def __init__(self, peak_flops: float = PEAK_FLOPS,
-                 hbm_bw: float = HBM_BW, ici_bw: float = ICI_BW):
-        self.peak_flops = peak_flops
-        self.hbm_bw = hbm_bw
-        self.ici_bw = ici_bw
-
-    def analyze(self, *, arch: str, shape: str, mesh: str, chips: int,
-                hlo_flops: float, hbm_bytes: float, collective_bytes: float,
-                model_flops: float) -> RooflineResult:
-        """All inputs are *global* (whole-program) quantities; terms are
-        per-chip seconds assuming perfect balance (cost_analysis reports the
-        SPMD-partitioned module, i.e. per-device work, times 1; we pass
-        per-device numbers scaled up by ``chips`` for clarity)."""
-        return RooflineResult(
-            arch=arch, shape=shape, mesh=mesh, chips=chips,
-            compute_s=hlo_flops / (chips * self.peak_flops),
-            memory_s=hbm_bytes / (chips * self.hbm_bw),
-            collective_s=collective_bytes / (chips * self.ici_bw),
-            model_flops=model_flops, hlo_flops=hlo_flops,
-            hbm_bytes=hbm_bytes, collective_bytes=collective_bytes)

@@ -28,8 +28,8 @@ analogue for the repo's own jax/pallas workloads:
   ``QuerySpec`` the dashboard panel, the analysis rule, ``/query/v2``
   callers and the tests all share.
 
-Calibration-point convention: measured machine peaks (e.g. from
-``benchmarks/roofline.py`` microbenchmarks) are stored as ordinary
+Calibration-point convention: measured machine peaks (e.g. from a
+microbenchmark on the chip) are stored as ordinary
 ``marker`` points under the reserved region :data:`CALIB_REGION` with
 fields ``peak_flops`` / ``peak_bw``.  :func:`roofline_peaks` reads the
 latest one back; :func:`register_roofline_group` re-registers ROOFLINE

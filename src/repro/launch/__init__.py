@@ -1,1 +1,1 @@
-"""Launcher: production meshes, AOT dry-run, train/serve drivers."""
+"""Launcher: device meshes, step builders, train/serve drivers."""

@@ -1,8 +1,9 @@
-"""Post-SPMD HLO cost walker — the dry-run "profiler" (no hardware needed).
+"""Post-SPMD HLO cost walker over a compiled program (no hardware needed).
 
-``compiled.cost_analysis()`` counts while-loop bodies **once** (verified in
-EXPERIMENTS.md §Dry-run), which under-reports scanned-layer models by ~num
-layers; and it reports nothing about collectives.  This walker parses
+``compiled.cost_analysis()`` counts while-loop bodies **once**, which
+under-reports scanned-layer models by ~num layers
+(``tests/test_tpu_compile.py`` checks the walker counts every scanned
+layer); and it reports nothing about collectives.  This walker parses
 ``compiled.as_text()`` (the post-SPMD, per-partition module) and computes:
 
 * ``flops``       — dot/convolution/elementwise/reduce FLOPs, **x while

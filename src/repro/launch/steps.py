@@ -1,8 +1,8 @@
-"""Step builders + abstract input specs for AOT lowering (dry-run + drivers).
+"""Step builders + abstract input specs for AOT lowering and the drivers.
 
 Everything here is ShapeDtypeStruct-level: no allocation.  Input specs carry
 *logical axes* (same ParamSpec mechanism as model weights), so one rule table
-derives every sharding in the 80-compile dry-run matrix.
+derives every input sharding.
 """
 
 from __future__ import annotations
@@ -117,15 +117,13 @@ def _shard(spec_tree, rules, mesh):
 
 
 def make_pc(rules: ShardingRules, mesh: Optional[Mesh],
-            enable: bool = True,
-            seq_parallel: bool = False) -> PartitionConstraints:
-    return PartitionConstraints(rules, mesh, enable,
-                                seq_parallel=seq_parallel)
+            enable: bool = True) -> PartitionConstraints:
+    return PartitionConstraints(rules, mesh, enable)
 
 
 def _moe_localized(cfg: ModelConfig, mesh: Mesh) -> ModelConfig:
-    """Locality-aware MoE dispatch: one dispatch group per DP shard (§Perf:
-    keeps the routing sort/scatter shard-local).  When the expert count
+    """Locality-aware MoE dispatch: one dispatch group per DP shard (keeps
+    the routing sort/scatter shard-local).  When the expert count
     divides the TP axis on a single-pod mesh, upgrade to the shard_map
     ragged all-to-all dispatch (strictly less wire than GSPMD's masked-AR
     scatter; apply_moe re-checks shape divisibility and falls back)."""
@@ -146,8 +144,7 @@ def build_train_bundle(cfg: ModelConfig, shape: ShapeConfig,
                        rules: Optional[ShardingRules] = None) -> StepBundle:
     rules = rules or rules_for("train")
     cfg = _moe_localized(cfg, mesh)
-    pc = make_pc(rules, mesh,
-                 seq_parallel=getattr(train_cfg, "seq_parallel", False))
+    pc = make_pc(rules, mesh)
     pspecs = model_specs(cfg)
     ospecs = opt_state_specs(pspecs, train_cfg)
     ispecs = train_input_specs(cfg, shape)
@@ -204,7 +201,7 @@ def build_decode_bundle(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh,
     ispecs = decode_input_specs(cfg, shape)
     scalar = NamedSharding(mesh, P())
 
-    # cache-write policy (§Perf): when kv_heads takes the TP axis the cache
+    # cache-write policy: when kv_heads takes the TP axis the cache
     # sequence dim is unsharded -> in-place DUS (cheapest); when the seq dim
     # carries the TP axis instead (kv_heads not divisible), a dynamic-index
     # DUS would force collectives, so use the elementwise one-hot write.

@@ -184,74 +184,6 @@ def chunked_attention(q, k, v, *, causal=True, window=0, chunk_k=1024,
     return _ungroup(out.astype(q.dtype))
 
 
-def merge_partial(parts):
-    """Merge (m, l, acc) partial-softmax triples (for the recursive causal
-    decomposition used by the perf hillclimb)."""
-    m = parts[0][0]
-    for p in parts[1:]:
-        m = jnp.maximum(m, p[0])
-    l = sum(jnp.exp(pm - m) * pl for pm, pl, _ in parts)
-    acc = sum(jnp.exp(pm - m)[..., None] * pa for pm, pl, pa in parts)
-    return m, l, acc
-
-
-def _partial_full(q, k, v, *, causal, q_offset, k_offset, softcap=0.0):
-    """Un-normalized attention stats (m, l, acc) of q against k/v slice."""
-    b, sq, h, dd = q.shape
-    kvh = k.shape[2]
-    qg = _group(q, kvh)
-    kk = k.transpose(0, 2, 1, 3)
-    vv = v.transpose(0, 2, 1, 3)
-    s = jnp.einsum("bkgqd,bksd->bkgqs", qg, kk,
-                   preferred_element_type=jnp.float32)
-    s = _softcap(s * (1.0 / math.sqrt(dd)), softcap)
-    if causal:
-        q_pos = q_offset + jnp.arange(sq)
-        k_pos = k_offset + jnp.arange(k.shape[1])
-        s = s + _mask_bias(q_pos, k_pos, causal=True, window=0)
-    m = jnp.max(s, axis=-1)
-    p = jnp.exp(s - m[..., None])
-    l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(q.dtype), vv
-                     ).astype(jnp.float32)
-    return m, l, acc
-
-
-def recursive_causal_attention(q, k, v, *, levels=3, softcap=0.0,
-                               q_offset=0, k_offset=0):
-    """FLOP-exact causal attention via recursive block decomposition.
-
-    causal(S) = causal(S/2 lower) + dense(q_hi x k_lo) + causal(S/2 upper);
-    the dense block has no masked-out work, so wasted FLOPs drop from ~50%
-    (full masked) to S^2/2^(levels+1).  This is the XLA-path analogue of a
-    flash kernel's block skipping — used by the §Perf hillclimb.
-    """
-    def stats(q, k, v, level, q_off, k_off):
-        sq = q.shape[1]
-        if level == 0 or sq <= 128 or sq % 2:
-            return _partial_full(q, k, v, causal=True, q_offset=q_off,
-                                 k_offset=k_off, softcap=softcap)
-        half = sq // 2
-        q_lo, q_hi = q[:, :half], q[:, half:]
-        k_lo, k_hi = k[:, :half], k[:, half:]
-        v_lo, v_hi = v[:, :half], v[:, half:]
-        m1, l1, a1 = stats(q_lo, k_lo, v_lo, level - 1, q_off, k_off)
-        # strictly-lower dense rectangle: q_hi attends all of k_lo, unmasked
-        m2, l2, a2 = _partial_full(q_hi, k_lo, v_lo, causal=False,
-                                   q_offset=0, k_offset=0, softcap=softcap)
-        m3, l3, a3 = stats(q_hi, k_hi, v_hi, level - 1, q_off + half,
-                           k_off + half)
-        m_hi, l_hi, a_hi = merge_partial([(m2, l2, a2), (m3, l3, a3)])
-        m = jnp.concatenate([m1, m_hi], axis=-1)
-        l = jnp.concatenate([l1, l_hi], axis=-1)
-        a = jnp.concatenate([a1, a_hi], axis=-2)
-        return m, l, a
-
-    m, l, acc = stats(q, k, v, levels, q_offset, k_offset)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return _ungroup(out.astype(q.dtype))
-
-
 # --------------------------------------------------------------------------
 # GQA block (projections + rope + cache + attention)
 # --------------------------------------------------------------------------
@@ -264,9 +196,13 @@ def select_attn_impl(attn_impl: str, cfg: ModelConfig, seq_len: int, *,
     on one device (a ``pallas_call`` is not partitioned by GSPMD), GQA
     without a logit softcap, and a sequence and head dim the kernel's
     blocks tile.  Everything else, the CPU backend included, runs
-    ``full_attention``.  Any other value is taken as given."""
-    if attn_impl != "auto":
+    ``full_attention``.  ``"masked"`` and ``"flash"`` are taken as given;
+    any other name raises ``ValueError``."""
+    if attn_impl in ("masked", "flash"):
         return attn_impl
+    if attn_impl != "auto":
+        raise ValueError(f"unknown attn_impl {attn_impl!r}: "
+                         f"expected 'auto', 'masked' or 'flash'")
     from repro.kernels.flash_attention import fits
     if (backend == "tpu" and mesh_devices <= 1
             and cfg.attention_type == "gqa" and not cfg.attn_logit_softcap
@@ -342,13 +278,6 @@ def gqa_attention(p, x, cfg: ModelConfig, *, rope=None, mode="train",
             interpret = jax.default_backend() == "cpu"
             out = flash_attention_bshd(q, k, v, causal=causal,
                                        window=window, interpret=interpret)
-        elif attn_impl == "recursive" and causal and s >= 512:
-            out = recursive_causal_attention(q, k, v,
-                                             softcap=cfg.attn_logit_softcap)
-            if window:
-                # recursive path does not support SWA; fall back
-                out = full_attention(q, k, v, causal=causal, window=window,
-                                     softcap=cfg.attn_logit_softcap)
         else:
             out = full_attention(q, k, v, causal=causal, window=window,
                                  softcap=cfg.attn_logit_softcap)
@@ -457,10 +386,7 @@ def mla_attention(p, x, cfg: ModelConfig, *, rope, mode="train", cache=None,
                                       (b, s, h, a.qk_rope_head_dim))], axis=-1)
         qq = jnp.concatenate([q_nope, q_rope], axis=-1)
         if mode == "train":
-            if attn_impl == "recursive" and s >= 512:
-                out = recursive_causal_attention(qq, k, v)
-            else:
-                out = full_attention(qq, k, v, causal=True)
+            out = full_attention(qq, k, v, causal=True)
         else:
             out = chunked_attention(qq, k, v, causal=True)
         new_cache = None

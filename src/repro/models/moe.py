@@ -12,9 +12,9 @@ stay static for XLA):
   4. batched expert FFN over (E, C, d) — an einsum the MXU loves,
   5. weighted scatter-add back to token order.
 
-**Locality (§Perf hillclimb):** a single global dispatch makes the argsort/
-scatter a cross-mesh data-dependent shuffle — the dry-run showed it
-dominating DeepSeek-V2's collective term.  With ``dispatch_groups = DP``
+**Locality:** a single global dispatch makes the argsort/scatter a
+cross-mesh data-dependent shuffle, which dominates DeepSeek-V2's collective
+term on a large mesh.  With ``dispatch_groups = DP``
 the token axis is split into shard-aligned groups and every sort/scatter is
 batched over a sharded group dim (purely local under GSPMD); only the
 expert-parallel buffer exchange crosses the mesh.  Capacity is per-group, so
@@ -186,7 +186,7 @@ def apply_moe(p, x, cfg: ModelConfig, pc=None):
 
 
 # ==========================================================================
-# Expert-parallel ragged dispatch (opt-in, §Perf lever for DeepSeek-V2)
+# Expert-parallel ragged dispatch (opt-in, for DeepSeek-V2)
 # ==========================================================================
 
 
@@ -202,8 +202,7 @@ def apply_moe_a2a(p, x, cfg: ModelConfig, mesh):
     experts (weights FSDP-gathered over "data"), exchanges back, combines,
     and all-gathers the per-slice outputs over "model".  Wire volume
     ~= tokens x top_k x d / tp per device per direction — ~4x below the
-    fp32+u32 all-reduce pair GSPMD emits for the grouped scatter
-    (EXPERIMENTS.md §Perf, deepseek audit).
+    fp32+u32 all-reduce pair GSPMD emits for the grouped scatter.
 
     Preconditions (checked): single-pod mesh ("data","model"),
     num_experts % tp == 0, local tokens % tp == 0.  Shared experts and the

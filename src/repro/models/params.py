@@ -8,8 +8,7 @@ dtype, *logical axis names* and an initializer.  From one spec tree we derive:
 * ``NamedSharding`` trees via the logical-axis rules in ``repro.parallel``.
 
 This keeps shapes, shardings and initialization in a single source of truth,
-which is what makes the 40-cell dry-run tractable without per-arch hand
-tuning.
+so every architecture lowers on any mesh without per-arch hand tuning.
 """
 
 from __future__ import annotations

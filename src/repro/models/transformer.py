@@ -3,7 +3,7 @@
 One entry point, :func:`forward`, serves all 10 assigned architectures in all
 three execution modes (train / prefill / decode).  Layers are *scanned* with
 stacked parameters — essential to keep HLO size and compile time flat across
-60–96-layer configs in the 80-compile dry-run matrix.
+60–96-layer configs.
 
 Caches are pytrees stacked over the layer axis, so the same scan carries
 them; decode-time cache writes are one-hot selects (GSPMD-safe when the cache
@@ -127,7 +127,7 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int,
                 dtype=jnp.bfloat16):
     """ParamSpec tree for the decode caches (all zero-init).
 
-    Logical axes drive the dry-run shardings: KV caches shard batch over DP
+    Logical axes drive the cache shardings: KV caches shard batch over DP
     and kv_heads over TP, falling back to the cache sequence dim when
     kv_heads does not divide (see ``_AXIS_PRIORITY`` in parallel.sharding).
     """
@@ -314,8 +314,7 @@ def _sinusoidal(positions, d):
     return jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
 
 
-def _scan_layers(body, x, stacked_params, stacked_cache, *, remat="none",
-                 unroll: int = 1):
+def _scan_layers(body, x, stacked_params, stacked_cache, *, remat="none"):
     """Scan ``body(x, layer_params, layer_cache) -> (x, new_cache, aux)``."""
     if remat != "none":
         policy = {"minimal": jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
@@ -329,14 +328,13 @@ def _scan_layers(body, x, stacked_params, stacked_cache, *, remat="none",
         return (x, _combine_aux(aux_acc, aux)), new_cache
 
     (x, aux), new_caches = jax.lax.scan(
-        step, (x, _zero_aux()), (stacked_params, stacked_cache),
-        unroll=unroll)
+        step, (x, _zero_aux()), (stacked_params, stacked_cache))
     return x, new_caches, aux
 
 
 def forward(params, cfg: ModelConfig, *, tokens, mode="train", cache=None,
             pos=None, pc=None, extras=None, attn_impl="auto",
-            remat="none", scan_unroll: int = 1, cache_update="onehot"):
+            remat="none", cache_update="onehot"):
     """Run the model.
 
     tokens: (B, S) int32.  decode: S is the number of new tokens (1).
@@ -379,19 +377,19 @@ def forward(params, cfg: ModelConfig, *, tokens, mode="train", cache=None,
         lc = cache if cache is not None else _dummy_cache(cfg, b, mode)
         x, new_cache, aux = _scan_layers(body, x, params["layers"], lc,
                                          remat=remat if mode == "train"
-                                         else "none", unroll=scan_unroll)
+                                         else "none")
 
     elif cfg.family == "hybrid":
         x, new_cache, aux = _hybrid_forward(
             params, x, cfg, mode=mode, cache=cache, pos=pos, rope=rope,
             pc=pc, attn_impl=attn_impl, remat=remat,
-            scan_unroll=scan_unroll, cache_update=cache_update)
+            cache_update=cache_update)
 
     elif cfg.family == "encdec":
         x, new_cache, aux = _encdec_forward(
             params, x, cfg, mode=mode, cache=cache, pos=pos, pc=pc,
             extras=extras, attn_impl=attn_impl, remat=remat,
-            scan_unroll=scan_unroll, cache_update=cache_update)
+            cache_update=cache_update)
 
     else:
         new_cache = {}
@@ -410,8 +408,7 @@ def forward(params, cfg: ModelConfig, *, tokens, mode="train", cache=None,
             lc = cache[key] if cache is not None \
                 else _dummy_cache(cfg, b, mode,
                                   n=jax.tree.leaves(params[group])[0].shape[0])
-            x, nc, a = _scan_layers(body, x, params[group], lc, remat=trem,
-                                    unroll=scan_unroll)
+            x, nc, a = _scan_layers(body, x, params[group], lc, remat=trem)
             new_cache[key] = nc
             aux = _combine_aux(aux, a)
         if not any(k in params for k in ("dense_layers", "moe_layers")):
@@ -433,7 +430,7 @@ def _dummy_cache(cfg, batch, mode, n=None):
 
 
 def _hybrid_forward(params, x, cfg, *, mode, cache, pos, rope, pc, attn_impl,
-                    remat, scan_unroll, cache_update="onehot"):
+                    remat, cache_update="onehot"):
     ae = cfg.hybrid.attn_every
     nsb = cfg.hybrid.num_shared_blocks
     aux = _zero_aux()
@@ -448,7 +445,7 @@ def _hybrid_forward(params, x, cfg, *, mode, cache, pos, rope, pc, attn_impl,
         # 500k KV at long context); they ride the scan CARRY with per-group
         # dynamic slice/update so XLA keeps one aliased buffer — as scan
         # xs/ys they would be double-buffered and re-stacked every step
-        # (§Perf: zamba2 long_500k memory term -~2x).
+        # (zamba2 long_500k memory term -~2x).
         def group_body(carry, xs):
             x, aux_acc, ac_all = carry
             gp, gc, gi = xs
@@ -507,8 +504,7 @@ def _hybrid_forward(params, x, cfg, *, mode, cache, pos, rope, pc, attn_impl,
             return x, nc, _zero_aux()
         rc = cache["rem"] if cache is not None else _dummy_cache(
             cfg, b, mode, n=jax.tree.leaves(params["rem"])[0].shape[0])
-        x, new_rc, _ = _scan_layers(body, x, params["rem"], rc, remat=trem,
-                                    unroll=scan_unroll)
+        x, new_rc, _ = _scan_layers(body, x, params["rem"], rc, remat=trem)
         new_cache["rem"] = new_rc
     return x, (new_cache if mode != "train" else None), aux
 
@@ -543,7 +539,7 @@ def encdec_cross_caches(params, cfg: ModelConfig, enc_out):
 
 
 def _encdec_forward(params, x, cfg, *, mode, cache, pos, pc, extras,
-                    attn_impl, remat, scan_unroll, cache_update="onehot"):
+                    attn_impl, remat, cache_update="onehot"):
     trem = remat if mode == "train" else "none"
     b = x.shape[0]
     if mode in ("train", "prefill"):
@@ -563,8 +559,7 @@ def _encdec_forward(params, x, cfg, *, mode, cache, pos, pc, extras,
     sc = cache["self"] if cache is not None else _dummy_cache(cfg, b, mode,
                                                               n=n)
     x, new_sc, aux = _scan_layers(body, x, params["dec_layers"],
-                                  (sc, cross_caches), remat=trem,
-                                  unroll=scan_unroll)
+                                  (sc, cross_caches), remat=trem)
     new_cache = None
     if mode != "train":
         new_cache = {"self": new_sc,
@@ -580,13 +575,12 @@ def _encdec_forward(params, x, cfg, *, mode, cache, pos, pc, extras,
 
 
 def loss_fn(params, cfg: ModelConfig, batch, *, pc=None, attn_impl="auto",
-            remat="none", scan_unroll: int = 1):
+            remat="none"):
     """Next-token CE loss + aux.  batch: {"tokens", "labels", extras...}."""
     extras = {k: v for k, v in batch.items() if k not in ("tokens", "labels")}
     logits, _, aux = forward(params, cfg, tokens=batch["tokens"],
                              mode="train", pc=pc, extras=extras,
-                             attn_impl=attn_impl, remat=remat,
-                             scan_unroll=scan_unroll)
+                             attn_impl=attn_impl, remat=remat)
     with jax.named_scope("logits_loss"):
         mask = (batch["labels"] >= 0)
         labels = jnp.maximum(batch["labels"], 0)

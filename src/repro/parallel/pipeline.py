@@ -9,7 +9,7 @@ Mapping: the stage loop runs inside ``jax.shard_map`` over the ``pipe``
 mesh axis.  Stage s holds the stacked params slice s (in_spec P("pipe")),
 microbatches tick through ``num_microbatches + stages - 1`` steps, and the
 inter-stage handoff is ``jax.lax.ppermute`` (lowered to collective-permute —
-neighbor-only traffic, visible in the dry-run HLO).  The bubble fraction is
+neighbor-only traffic, visible in the lowered HLO).  The bubble fraction is
 the usual (S-1)/(M+S-1); pick M >= 4*S in production.
 
 This module is the distribution substrate's PP building block: it is
@@ -89,5 +89,5 @@ def pipeline_apply(stage_fn: Callable, stage_params, x, *, mesh: Mesh,
 
 
 def bubble_fraction(stages: int, num_microbatches: int) -> float:
-    """Pipeline bubble overhead (the napkin-math term used in §Perf)."""
+    """Pipeline bubble overhead (the usual napkin-math term)."""
     return (stages - 1) / (num_microbatches + stages - 1)

@@ -5,7 +5,7 @@ Models name their dimensions with *logical axes* (``"embed"``, ``"heads"``,
 or more mesh axes.  At bind time every rule is checked for divisibility
 against the actual dimension size and the actual mesh; rules that do not
 divide are **dropped to replication** (never an error).  This single fallback
-keeps all 40 (arch x shape) dry-run cells compiling without per-arch hand
+keeps every (arch x shape x mesh) combination compiling without per-arch hand
 tuning:
 
 * phi3-medium kv_heads=10, granite/yi/mixtral/nemotron kv<=8 < model=16
@@ -66,7 +66,7 @@ class ShardingRules:
 # present only on the multi-pod mesh; missing axes are dropped at bind time).
 TRAIN_RULES = ShardingRules({
     "batch": ("pod", "data"),
-    "seq": None,                  # SP toggled via with_overrides(seq="model")
+    "seq": None,
     "embed": "data",              # FSDP / ZeRO-3 weight sharding
     "heads": "model",
     "kv_heads": "model",
@@ -153,16 +153,10 @@ class PartitionConstraints:
     """
 
     def __init__(self, rules: ShardingRules, mesh: Optional[Mesh] = None,
-                 enable: bool = True, seq_parallel: bool = False):
+                 enable: bool = True):
         self.rules = rules
         self.mesh = mesh
         self.enable = enable and mesh is not None
-        # Megatron-style sequence parallelism: the inter-block residual
-        # stream (and with it every layer-boundary activation the scan
-        # saves for backward) is sharded over the TP axis along *sequence*;
-        # attention/MLP projections are per-token so only K/V need a
-        # (small, GQA-sized) gather per layer.
-        self.seq_parallel = seq_parallel
 
     def _constraint(self, x, logical_axes: tuple):
         if not self.enable:
@@ -188,24 +182,7 @@ class PartitionConstraints:
     # -- common patterns ----------------------------------------------------
 
     def tokens(self, x):                       # (B, S, d)
-        if self.seq_parallel and x.ndim == 3 and \
-                x.shape[1] % self._tp_size() == 0:
-            return self.tokens_sp(x)
         return self.act(x, "batch", "seq", "embed")
-
-    def _tp_size(self) -> int:
-        if self.mesh is None:
-            return 1
-        return _mesh_axis_sizes(self.mesh).get("model", 1)
-
-    def tokens_sp(self, x):
-        """Sequence-parallel region: seq over the TP axis (norms, residual)."""
-        if not self.enable:
-            return x
-        rules = self.rules.with_overrides(seq="model", embed=None)
-        pspec = logical_to_pspec(("batch", "seq", "embed"), x.shape, rules,
-                                 self.mesh)
-        return self._apply(x, pspec)
 
     def heads(self, x):                        # (B, S, H, D)
         return self.act(x, "batch", "seq", "heads", None)

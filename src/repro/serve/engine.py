@@ -1,8 +1,7 @@
 """Serving layer: jitted prefill/decode steps + a batched request engine.
 
-``make_serve_fns`` builds the two step functions the dry-run lowers for the
-``prefill_32k`` / ``decode_32k`` / ``long_500k`` cells; :class:`ServingEngine`
-is the runnable engine used by the serving example — batched greedy decoding
+``make_serve_fns`` builds the jit-able prefill and decode step functions;
+:class:`ServingEngine` is the runnable engine used by the serving example — batched greedy decoding
 with per-request and per-step metrics emitted to the LMS (time-to-first-token,
 decode throughput), so a *serving* job is monitored exactly like a training
 job (paper's "jobs" are agnostic to what runs inside).

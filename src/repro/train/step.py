@@ -6,7 +6,8 @@ Builds the jit-able ``train_step(params, opt_state, batch, step)`` for a
 * microbatched gradient accumulation (``num_microbatches``) via lax.scan,
   fp32 accumulators;
 * global-norm clipping;
-* remat policy + attention implementation knobs (the §Perf levers);
+* remat policy knob; attention resolves ``"auto"`` per backend and mesh
+  (``models.attention.select_attn_impl``);
 * hierarchical gradient sync: per-pod gradients under a manual-``pod``
   shard_map with int8 compression on the slow cross-pod links, while
   GSPMD keeps managing FSDP/TP inside the pod (``grad_compression`` knob);
@@ -36,34 +37,24 @@ def _grads_and_metrics(params, batch, model_cfg: ModelConfig,
     """Microbatched value_and_grad; returns (grads fp32, metrics)."""
     nm = train_cfg.num_microbatches
     vg = jax.value_and_grad(
-        partial(loss_fn, cfg=model_cfg, pc=pc,
-                attn_impl=getattr(train_cfg, "attn_impl", "auto"),
-                remat=train_cfg.remat_policy,
-                scan_unroll=getattr(train_cfg, "scan_unroll", 1)),
+        partial(loss_fn, cfg=model_cfg, pc=pc, attn_impl="auto",
+                remat=train_cfg.remat_policy),
         has_aux=True)
 
-    sync_dt = jnp.dtype(getattr(train_cfg, "grad_sync_dtype", "float32"))
-
-    def _sync_cast(grads):
-        """Cast pre-reduction gradients so the DP all-reduce runs at the
-        configured precision (bf16 halves the dominant collective volume;
-        the optimizer math stays fp32)."""
-        if sync_dt == jnp.float32:
-            return jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        return jax.tree.map(
-            lambda g: g.astype(sync_dt).astype(jnp.float32), grads)
+    def f32(grads):
+        return jax.tree.map(lambda g: g.astype(jnp.float32), grads)
 
     if nm <= 1:
         (loss, metrics), grads = vg(params, batch=batch)
-        return _sync_cast(grads), metrics
+        return f32(grads), metrics
 
     # Interleaved microbatch split: (B, ...) -> (nm, B/nm, ...) where
     # microbatch m takes rows {m, m+nm, m+2nm, ...}.  Each DP shard's
     # contiguous row-block then contributes one row to EVERY microbatch, so
     # the per-microbatch slice keeps the full (pod, data) batch sharding —
     # a contiguous split would leave microbatches spanning a fraction of
-    # the DP axis and GSPMD silently replicates the rest (verified in the
-    # dry-run: 10x per-device FLOPs on the 2x16x16 mesh).
+    # the DP axis and GSPMD silently replicates the rest (10x per-device
+    # FLOPs on a 2x16x16 mesh).
     def split(x):
         return x.reshape((x.shape[0] // nm, nm) + x.shape[1:]).swapaxes(0, 1)
     mbatch = jax.tree.map(split, batch)
@@ -82,7 +73,7 @@ def _grads_and_metrics(params, batch, model_cfg: ModelConfig,
                 "moe_dropped_frac": jnp.float32(0),
                 "moe_max_load": jnp.float32(0)}
     (grads, metrics), _ = jax.lax.scan(body, (zeros, zmetrics), mbatch)
-    return _sync_cast(grads), metrics
+    return f32(grads), metrics
 
 
 def make_train_step(model_cfg: ModelConfig, train_cfg: TrainConfig, *,

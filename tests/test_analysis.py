@@ -1,13 +1,12 @@
-"""Pathological-job rules, pattern decision tree, roofline analyzer."""
+"""Pathological-job rules and the pattern decision tree."""
 
 import pytest
 
-from repro.core.analysis import (DEFAULT_TREE, RooflineAnalyzer,
-                                 StreamAnalyzer, ThresholdRule, classify_job,
-                                 default_rules, evaluate_rule,
+from repro.core.analysis import (StreamAnalyzer, ThresholdRule,
+                                 classify_job, default_rules, evaluate_rule,
                                  evaluate_rules_on_db)
 from repro.core.line_protocol import Point
-from repro.core.perf_groups import HBM_BW, ICI_BW, PEAK_FLOPS, derive_all
+from repro.core.perf_groups import PEAK_FLOPS, derive_all
 from repro.core.tsdb import Database
 
 S = 1_000_000_000   # ns
@@ -82,23 +81,6 @@ def test_decision_tree_branches():
         assert out["pattern"] == want, (metrics, out)
         assert out["remedy"]
         assert out["path"]
-
-
-def test_roofline_terms():
-    an = RooflineAnalyzer()
-    r = an.analyze(arch="a", shape="s", mesh="m", chips=256,
-                   hlo_flops=256 * PEAK_FLOPS,          # 1 s of compute
-                   hbm_bytes=256 * HBM_BW * 2,          # 2 s of memory
-                   collective_bytes=256 * ICI_BW * 0.5,
-                   model_flops=128 * PEAK_FLOPS)
-    assert r.compute_s == pytest.approx(1.0)
-    assert r.memory_s == pytest.approx(2.0)
-    assert r.collective_s == pytest.approx(0.5)
-    assert r.dominant == "memory"
-    assert r.bound_s == pytest.approx(2.0)
-    assert r.useful_flop_ratio == pytest.approx(0.5)
-    cls = r.classify()
-    assert cls["pattern"] in ("memory-bound", "recompute-heavy memory-bound")
 
 
 def test_perf_groups_derive():

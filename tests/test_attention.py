@@ -1,4 +1,4 @@
-"""Attention path equivalences: chunked/recursive/decode vs dense masked."""
+"""Attention path equivalences: chunked/decode vs dense masked."""
 
 import jax
 import jax.numpy as jnp
@@ -7,8 +7,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models.attention import (chunked_attention, full_attention,
-                                    gqa_attention, mla_attention,
-                                    recursive_causal_attention)
+                                    gqa_attention, mla_attention)
 from repro.models.layers import rope_table
 from repro.models.params import init_params
 from repro.models.attention import attn_specs, mla_specs
@@ -31,10 +30,11 @@ def test_chunked_matches_full(rng, kv, window):
                                atol=2e-5)
 
 
-def test_recursive_matches_full(rng):
+def test_chunked_matches_full_s512(rng):
+    """A 512-long causal sequence in four key chunks."""
     q, k, v = _qkv(rng, 1, 512, 4, 4, 16)
     want = full_attention(q, k, v, causal=True)
-    got = recursive_causal_attention(q, k, v, levels=2)
+    got = chunked_attention(q, k, v, causal=True, window=0, chunk_k=128)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
                                atol=2e-5)
 
@@ -147,13 +147,20 @@ def test_moe_dispatch_invariants(rng):
     ("auto", "tpu", 1, {"sliding_window": 4096}, 2048, "flash"),
     ("masked", "tpu", 1, {}, 2048, "masked"),           # explicit choices
     ("flash", "cpu", 1, {}, 2048, "flash"),
+    ("recursive", "tpu", 1, {}, 2048, ValueError),      # unknown names
+    ("flsh", "cpu", 1, {}, 2048, ValueError),
 ])
 def test_select_attn_impl(impl, backend, devices, changes, seq, want):
     """The train path's attention as a pure function of backend, mesh
-    size, softcap, attention type and shape."""
+    size, softcap, attention type and shape; an unknown name raises."""
     import dataclasses
     from repro.models.attention import select_attn_impl
     cfg = dataclasses.replace(get_config("granite-3-8b"), **changes)
+    if want is ValueError:
+        with pytest.raises(ValueError, match=impl):
+            select_attn_impl(impl, cfg, seq, mesh_devices=devices,
+                             backend=backend)
+        return
     assert select_attn_impl(impl, cfg, seq, mesh_devices=devices,
                             backend=backend) == want
 
